@@ -31,7 +31,7 @@ def adapter_payloads(arch: str) -> dict:
     tree of the architecture (counts measured on the pytree, not derived)."""
     cfg = get_config(arch)
     adapter = jax.eval_shape(
-        lambda: model.init_params(cfg, jax.random.key(0)))["adapter"]
+        lambda: model.init_adapter(cfg, jax.random.key(0)))
     leaves = jax.tree.flatten(adapter, is_leaf=tri_lora.is_adapter)[0]
     a = sum(int(x["A"].size) for x in leaves)
     b = sum(int(x["B"].size) for x in leaves)

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 
 def _crc_of(items: dict) -> int:
     """Content checksum over key names + raw array bytes, key-sorted so it
@@ -73,7 +71,7 @@ def verify(path: str) -> None:
 
 
 def _flatten(tree: Any):
-    flat, treedef = compat.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = {}
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
@@ -135,7 +133,7 @@ def restore(path: str, like: Any, *, as_numpy: bool = False) -> Any:
     verify(path)
     with _open(path) as data:
         dtypes = json.loads(bytes(data["__dtypes__"]).decode())
-        flat_like, treedef = compat.tree_flatten_with_path(like)
+        flat_like, treedef = jax.tree.flatten_with_path(like)
         leaves = []
         for pth, leaf in flat_like:
             key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

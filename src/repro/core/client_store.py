@@ -166,14 +166,9 @@ class ShardedClientStore:
         self.mesh = mesh_lib.make_client_mesh(self.m)
         self._place = functools.partial(mesh_lib.shard_clients, self.mesh)
         self._stacked = self._place(client_batch.stack_states(states))
-        if self.m % self.mesh.devices.size:
-            raise AssertionError(   # make_client_mesh picks a divisor
-                f"mesh size {self.mesh.devices.size} does not divide "
-                f"m={self.m}")
-        from jax.experimental.shard_map import shard_map
 
         @jax.jit
-        @functools.partial(shard_map, mesh=self.mesh,
+        @functools.partial(jax.shard_map, mesh=self.mesh,
                            in_specs=(P("clients"), P()), out_specs=P())
         def _gather(block_tree, ids):
             lo = jax.lax.axis_index("clients") * (self.m
@@ -191,7 +186,7 @@ class ShardedClientStore:
             return jax.tree.map(one, block_tree)
 
         @jax.jit
-        @functools.partial(shard_map, mesh=self.mesh,
+        @functools.partial(jax.shard_map, mesh=self.mesh,
                            in_specs=(P("clients"), P(), P()),
                            out_specs=P("clients"))
         def _scatter(block_tree, ids, vals_tree):

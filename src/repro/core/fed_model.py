@@ -39,7 +39,7 @@ class FedTask(NamedTuple):
 
     def init_client(self, key: jax.Array) -> dict:
         k1, k2 = jax.random.split(key)
-        adapter = model.init_params(self.cfg, k1)["adapter"]
+        adapter = model.init_adapter(self.cfg, k1)
         head = (jax.random.normal(k2, (self.cfg.d_model, self.n_classes))
                 * 0.02).astype(jnp.float32)
         return {"adapter": adapter, "head": head}
@@ -67,7 +67,7 @@ class FedTask(NamedTuple):
     def features(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """Frozen-backbone features for the GMM data-similarity (B=0 adapter
         ⇒ ΔW = 0, so features are adapter-independent)."""
-        adapter = model.init_params(self.cfg, jax.random.key(0))["adapter"]
+        adapter = model.init_adapter(self.cfg, jax.random.key(0))
         hidden, _, _ = model.forward_hidden(self.cfg, self.base, adapter,
                                             {"tokens": tokens},
                                             attn_impl=self.cfg.attn_impl)

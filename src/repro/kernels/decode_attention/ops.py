@@ -15,8 +15,7 @@ from repro.kernels.decode_attention.decode_attention import (
 from repro.kernels.decode_attention.grouped import (
     grouped_tri_lora_gemv_kernel,
 )
-
-_INTERPRET_DEFAULT = jax.default_backend() == "cpu"
+from repro.kernels.interpret import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -25,8 +24,7 @@ def decode_attention(q, k_cache, v_cache, idx, *, bk: int = 512,
     """q (B,1,H,hd); k/v_cache (B,R,K,hd); idx () or (B,) int32 (ragged
     per-row newest positions; -1 = masked slot, output row exactly zero)
     → (B,1,H,hd)."""
-    if interpret is None:
-        interpret = _INTERPRET_DEFAULT
+    interpret = interpret_mode(interpret)
     ring = k_cache.shape[1]
     bk_eff = min(bk, ring)
     pad = (-ring) % bk_eff
@@ -54,8 +52,7 @@ def grouped_dense(rows, x, w, a, c, b, *, scaling: float = 1.0,
     g = rows[i] (-1 = masked → exactly-zero row).  x (B,K); w (K,N); bank
     a (m,K,r) / c (m,r,r) / b (m,r,N).  Pads K and N to tile multiples
     (zero K-pads contribute nothing; N-pads are sliced off)."""
-    if interpret is None:
-        interpret = _INTERPRET_DEFAULT
+    interpret = interpret_mode(interpret)
     k, n = w.shape
     bk_eff, bn_eff = min(bk, k), min(bn, n)
     pad_k, pad_n = (-k) % bk_eff, (-n) % bn_eff

@@ -25,6 +25,13 @@ innermost and accumulates pᵀ@dO and dsᵀ@Q per KV tile, one pass for both
 cotangents.  The ``where`` is applied AFTER the exp on the raw scores so a
 fully-masked row (lse ≈ −1e30) yields p = 0 rather than exp(0) = 1.
 
+Per-row statistics (the logsumexp and the backward's Σ dO·O) cross HBM
+as (…, S, 128) lane-replicated blocks: the TPU lowering requires the last
+two block dims to be divisible by (8, 128) or to equal the array's, which a
+(1, bq) row block of a (B, H, S) array is not.  The public shapes stay
+(B, H, S): the forward slices lane 0 out and the backward broadcasts it
+back in.
+
 VMEM per step ≈ bq·hd (q) + 2·bk·hd (k,v) + bq·bk (logits) + bq·hd (acc)
 f32 — with bq=bk=512, hd=128: ~2.6 MB, comfortably inside one core's VMEM.
 """
@@ -38,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128    # lane width of the replicated per-row statistic blocks
 
 
 def _band(q_first, k_first, *, causal: bool, window: int, bq: int, bk: int):
@@ -118,7 +126,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float, causal: bool,
         denom = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
         if save_lse:
-            lse_ref[0, 0] = m_ref[...] + jnp.log(denom)
+            lse = (m_ref[...] + jnp.log(denom))[:, None]
+            lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
 def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -141,9 +150,10 @@ def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                               lambda bb, hh, qi, ki: (bb, hh, qi, 0))]
     out_shape = [jax.ShapeDtypeStruct((b, h, sq, hd), q.dtype)]
     if save_lse:
-        out_specs.append(pl.BlockSpec((1, 1, bq),
-                                      lambda bb, hh, qi, ki: (bb, hh, qi)))
-        out_shape.append(jax.ShapeDtypeStruct((b, h, sq), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, bq, LANES),
+                                      lambda bb, hh, qi, ki: (bb, hh, qi, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, sq, LANES),
+                                              jnp.float32))
     res = pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, causal=causal,
                           window=window, bq=bq, bk=bk, n_kv=n_kv,
@@ -166,7 +176,7 @@ def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         interpret=interpret,
     )(q, k, v)
-    return tuple(res) if save_lse else res[0]
+    return (res[0], res[1][..., 0]) if save_lse else res[0]
 
 
 # ---------------------------------------------------------------------------
@@ -193,16 +203,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)           # (bk, hd)
         v = v_ref[0, 0].astype(jnp.float32)           # (bk, hd)
         do = do_ref[0, 0].astype(jnp.float32)         # (bq, hd)
-        lse = lse_ref[0, 0]                           # (bq,) f32
-        delta = delta_ref[0, 0]                       # (bq,) f32  Σ dO·O
+        lse = lse_ref[0, 0, :, :1]                    # (bq, 1) f32
+        delta = delta_ref[0, 0, :, :1]                # (bq, 1) f32  Σ dO·O
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         mask = _mask(q_first, k_first, causal=causal, window=window,
                      bq=bq, bk=bk)
         # where AFTER exp: fully-masked rows (lse ≈ NEG_INF) must give p=0,
         # not exp(NEG_INF − lse) = 1; in-band entries satisfy s ≤ lse.
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         acc_ref[...] += jnp.dot(ds, k,
                                 preferred_element_type=jnp.float32) * sm_scale
 
@@ -234,15 +244,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)           # (bk, hd)
         v = v_ref[0, 0].astype(jnp.float32)           # (bk, hd)
         do = do_ref[0, 0].astype(jnp.float32)         # (bq, hd)
-        lse = lse_ref[0, 0]                           # (bq,) f32
-        delta = delta_ref[0, 0]                       # (bq,) f32
+        lse = lse_ref[0, 0, :, :1]                    # (bq, 1) f32
+        delta = delta_ref[0, 0, :, :1]                # (bq, 1) f32
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         mask = _mask(q_first, k_first, causal=causal, window=window,
                      bq=bq, bk=bk)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk_acc[...] += jnp.dot(ds.T, q,
                                preferred_element_type=jnp.float32) * sm_scale
 
@@ -275,11 +285,15 @@ def flash_attention_bwd_kernel(q, k, v, o, lse, do, *, causal: bool = True,
     n_q, n_kv = sq // bq, skv // bk
     sm_scale = float(hd) ** -0.5
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    # per-row statistics cross HBM lane-replicated (see the module docstring)
+    lse, delta = (jnp.broadcast_to(x[..., None], x.shape + (LANES,))
+                  for x in (lse, delta))
 
     q_spec = pl.BlockSpec((1, 1, bq, hd), lambda bb, hh, qi, ki: (bb, hh, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, hd),
                            lambda bb, hh, qi, ki, g=g: (bb, hh // g, ki, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda bb, hh, qi, ki: (bb, hh, qi))
+    row_spec = pl.BlockSpec((1, 1, bq, LANES),
+                            lambda bb, hh, qi, ki: (bb, hh, qi, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
                           window=window, bq=bq, bk=bk, n_kv=n_kv),
@@ -299,9 +313,9 @@ def flash_attention_bwd_kernel(q, k, v, o, lse, do, *, causal: bool = True,
         lambda bb, hh, ki, ji, g=g, n_q=n_q: (bb, hh * g + ji // n_q,
                                               ji % n_q, 0))
     rowj_spec = pl.BlockSpec(
-        (1, 1, bq),
+        (1, 1, bq, LANES),
         lambda bb, hh, ki, ji, g=g, n_q=n_q: (bb, hh * g + ji // n_q,
-                                              ji % n_q))
+                                              ji % n_q, 0))
     kj_spec = pl.BlockSpec((1, 1, bk, hd),
                            lambda bb, hh, ki, ji: (bb, hh, ki, 0))
     dk, dv = pl.pallas_call(

@@ -21,8 +21,7 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention.flash_attention import (
     flash_attention_bwd_kernel, flash_attention_kernel)
-
-_INTERPRET_DEFAULT = jax.default_backend() == "cpu"
+from repro.kernels.interpret import interpret_mode
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -61,8 +60,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     recompute-from-logsumexp kernels (custom VJP above), so residual memory
     stays O(S) per head instead of the O(S²) probability matrix.
     """
-    if interpret is None:
-        interpret = _INTERPRET_DEFAULT
+    interpret = interpret_mode(interpret)
     sq = q.shape[1]
     bq = min(bq, 1 << (sq - 1).bit_length())
     bk = min(bk, bq)

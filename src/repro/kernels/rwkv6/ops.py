@@ -6,9 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.interpret import interpret_mode
 from repro.kernels.rwkv6.rwkv6 import wkv6_kernel
-
-_INTERPRET_DEFAULT = jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -16,8 +15,7 @@ def wkv6(r, k, v, w, u, state, *, chunk: int = 32,
          interpret: bool | None = None):
     """r,k,v,w: (B,T,H,hd); u: (H,hd); state: (B,H,hd,hd) f32.
     Returns (y (B,T,H,hd) f32, new state (B,H,hd,hd) f32)."""
-    if interpret is None:
-        interpret = _INTERPRET_DEFAULT
+    interpret = interpret_mode(interpret)
     b, t, h, hd = r.shape
     eff_chunk = min(chunk, t)
     # pad time to a chunk multiple with w=1 (no decay), k=0 (no state write)

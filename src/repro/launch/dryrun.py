@@ -58,7 +58,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
     batch_abs = st.input_specs(cfg, shape_name)
     bspec = shd.batch_specs(batch_abs, mesh, baxes)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if fed:
             assert multi_pod, "federated round step needs the pod axis"
             step = st.make_fed_round_step(

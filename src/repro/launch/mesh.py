@@ -25,15 +25,22 @@ SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
 
+def _auto_mesh(shape: tuple, axes: tuple) -> jax.sharding.Mesh:
+    """Mesh whose axes GSPMD partitions on its own: the model's sharding
+    constraints stay hints, as they are written, under ``jax.set_mesh``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """1-device mesh for CPU smoke runs (same axis names, trivial extents)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:
@@ -46,19 +53,18 @@ def batch_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:
 # ---------------------------------------------------------------------------
 
 def make_client_mesh(n_clients: int | None = None, devices=None) -> Mesh:
-    """1-D ``("clients",)`` mesh over local devices.
+    """1-D ``("clients",)`` mesh over every local device.
 
-    Uses the largest device count that divides ``n_clients`` so the stacked
-    client axis splits evenly (GSPMD requires divisibility); degrades to a
-    single-device mesh — where the shard path is exactly the vmap path — on
-    hosts with one device or a client count coprime to the device count.
+    The stacked client axis must split evenly over the mesh (GSPMD requires
+    divisibility), so a client count that the device count does not divide
+    is refused: the mesh never quietly leaves devices idle.
     """
-    devices = jax.devices() if devices is None else list(devices)
-    if n_clients is None:
-        d = len(devices)
-    else:
-        d = max(k for k in range(1, len(devices) + 1) if n_clients % k == 0)
-    return Mesh(np.asarray(devices[:d]), ("clients",))
+    devices = jax.local_devices() if devices is None else list(devices)
+    if n_clients is not None and n_clients % len(devices):
+        raise ValueError(f"{n_clients} clients do not split evenly over "
+                         f"{len(devices)} devices; use a multiple of "
+                         f"{len(devices)}")
+    return Mesh(np.asarray(devices), ("clients",))
 
 
 def client_axis_sharding(mesh: Mesh, tree) -> object:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -31,10 +32,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core.adapter_bank import AdapterBank
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import model
 from repro.models.config import get_config
+
+
+# the weights are arguments of every compiled step, never constants in it
+_decode_step = jax.jit(model.decode_step, static_argnums=0)
 
 
 def generate(cfg, params, prompts: jnp.ndarray, gen: int,
@@ -42,9 +47,6 @@ def generate(cfg, params, prompts: jnp.ndarray, gen: int,
     """prompts: (B, P) int32.  Returns (B, P+gen) tokens."""
     b, p = prompts.shape
     cache = model.init_decode_cache(cfg, b, p + gen)
-
-    decode = jax.jit(lambda c, bt: model.decode_step(
-        cfg, params["base"], params["adapter"], c, bt))
 
     toks = [prompts[:, i:i + 1] for i in range(p)]
     out = list(toks)
@@ -54,7 +56,8 @@ def generate(cfg, params, prompts: jnp.ndarray, gen: int,
         cur = out[t]
         pos = (jnp.full((b, 1, 3), t, jnp.int32) if cfg.pos_type == "mrope"
                else jnp.full((b, 1), t, jnp.int32))
-        logits, cache = decode(cache, {"token": cur, "positions": pos})
+        logits, cache = _decode_step(cfg, params["base"], params["adapter"],
+                                     cache, {"token": cur, "positions": pos})
         if t >= p - 1:
             if greedy:
                 nxt = jnp.argmax(logits[:, -1], axis=-1)[:, None]
@@ -98,7 +101,7 @@ def make_requests(bank: AdapterBank, n: int, *, prompt_len: int, gen: int,
 def _with_positions(cache: dict, pos: jnp.ndarray) -> dict:
     """Install host-managed per-slot positions into every cache ``idx`` leaf
     — (q, B) for scanned layer groups, (B,) for tail blocks."""
-    flat, treedef = compat.tree_flatten_with_path(cache)
+    flat, treedef = jax.tree.flatten_with_path(cache)
     leaves = []
     for path, leaf in flat:
         last = str(getattr(path[-1], "key", getattr(path[-1], "idx",
@@ -113,6 +116,18 @@ def _with_positions(cache: dict, pos: jnp.ndarray) -> dict:
                 leaf = pos
         leaves.append(leaf)
     return jax.tree.unflatten(treedef, leaves)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _serve_step(cfg, base, bank_dec, cache, tok, pos, rows):
+    """One continuous-batching decode step; greedy next token per slot."""
+    cache = _with_positions(cache, pos)
+    positions = (jnp.broadcast_to(pos[:, None, None], (pos.shape[0], 1, 3))
+                 if cfg.pos_type == "mrope" else pos[:, None])
+    logits, cache = model.decode_step(
+        cfg, base, bank_dec, cache, {"token": tok, "positions": positions},
+        adapter_rows=rows)
+    return jnp.argmax(logits[:, -1], axis=-1), cache
 
 
 class ServeEngine:
@@ -130,17 +145,6 @@ class ServeEngine:
         self.cfg, self.base, self.bank = cfg, base, bank
         self.slots, self.max_len = slots, max_len
         self._bank_dec = bank.decode_tree()
-        self._decode = jax.jit(self._step)
-
-    def _step(self, cache, tok, pos, rows):
-        cache = _with_positions(cache, pos)
-        positions = (jnp.broadcast_to(pos[:, None, None],
-                                      (pos.shape[0], 1, 3))
-                     if self.cfg.pos_type == "mrope" else pos[:, None])
-        logits, cache = model.decode_step(
-            self.cfg, self.base, self._bank_dec, cache,
-            {"token": tok, "positions": positions}, adapter_rows=rows)
-        return jnp.argmax(logits[:, -1], axis=-1), cache
 
     def run(self, requests: Sequence[Request],
             progress: bool = False) -> Dict[int, np.ndarray]:
@@ -168,8 +172,9 @@ class ServeEngine:
                     pos[s] = 0                # slot REUSE: ring restarts; the
                     rows[s] = self.bank.lookup(r.user_id)   # validity mask
                     tok[s] = int(r.prompt[0])  # (slot <= idx) hides stale KV
-            nxt, cache = self._decode(cache, jnp.asarray(tok[:, None]),
-                                      jnp.asarray(pos), jnp.asarray(rows))
+            nxt, cache = _serve_step(self.cfg, self.base, self._bank_dec,
+                                     cache, jnp.asarray(tok[:, None]),
+                                     jnp.asarray(pos), jnp.asarray(rows))
             nxt = np.asarray(nxt)
             for s in range(self.slots):
                 r = active[s]
@@ -230,6 +235,7 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

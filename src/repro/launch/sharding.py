@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.models.config import ModelConfig
 
 # parameter names whose matrix maps "wide → d_model" (shard in-dim on model)
@@ -147,7 +146,7 @@ def param_specs(tree: Any, mesh: Mesh, cfg: ModelConfig, *,
     def spec(path, leaf):
         names = _path_names(path)
         return param_spec(names, tuple(leaf.shape), mesh, cfg, fsdp=fsdp)
-    return compat.tree_map_with_path(spec, tree)
+    return jax.tree.map_with_path(spec, tree)
 
 
 def cache_specs(tree: Any, mesh: Mesh, cfg: ModelConfig,
@@ -184,7 +183,7 @@ def cache_specs(tree: Any, mesh: Mesh, cfg: ModelConfig,
         if name == "h":                   # (B, rd)
             return P(*lead, bspec, _fits(body[1], mesh, "model"))
         return P(*((None,) * len(shape)))
-    return compat.tree_map_with_path(spec, tree)
+    return jax.tree.map_with_path(spec, tree)
 
 
 _CACHE_RANKS = {"k": 4, "v": 4, "xk": 4, "xv": 4, "wkv": 4, "shift": 2,
@@ -209,7 +208,7 @@ def batch_specs(batch_tree: Any, mesh: Mesh, batch: tuple[str, ...]) -> Any:
            or (isinstance(b, str) and shape[0] % _axis_size(mesh, b) != 0):
             b = None                       # long_500k: batch=1 → replicate
         return P(b, *((None,) * (len(shape) - 1)))
-    return compat.tree_map_with_path(spec, batch_tree)
+    return jax.tree.map_with_path(spec, batch_tree)
 
 
 def to_named(spec_tree: Any, mesh: Mesh) -> Any:

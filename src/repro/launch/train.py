@@ -51,6 +51,7 @@ eager driver's history.
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import time
 import warnings
@@ -67,13 +68,39 @@ from repro.core import (aggregation, client_batch, comm, compress, sampling,
 from repro.core import client_store as client_store_lib
 from repro.core.similarity import cka
 from repro.data import synthetic
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import model
-from repro.models.config import get_config
+from repro.models.config import ModelConfig, get_config
 from repro.optim import adamw, apply_updates
 
 
-def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
-        local_steps: int = 20, batch: int = 8, seq: int = 256,
+def make_local_fit(cfg: ModelConfig, opt):
+    """One client's local fit: a ``lax.scan`` of optimizer steps over its
+    (steps, B, S) token and label stacks.  The frozen ``base`` is an
+    argument and never a closed-over constant, so no compiled program
+    carries the weights; vmap it with ``in_axes=(None, 0, 0, 0)`` to fit
+    stacked clients against one shared base."""
+    def local_fit(base, adapter, toks, labs):
+        state = opt.init(adapter)
+
+        def step(carry, b):
+            ad, st = carry
+            (loss, _), g = jax.value_and_grad(
+                lambda a: model.loss_fn(cfg, a, base,
+                                        {"tokens": b[0], "labels": b[1]}),
+                has_aux=True)(ad)
+            upd, st = opt.update(g, st, ad)
+            return (apply_updates(ad, upd), st), loss
+
+        (adapter, _), losses = jax.lax.scan(step, (adapter, state),
+                                            (toks, labs))
+        return adapter, losses
+
+    return local_fit
+
+
+def run(arch: str | ModelConfig = "fed-100m", clients: int = 4,
+        rounds: int = 10, local_steps: int = 20, batch: int = 8, seq: int = 256,
         lr: float = 3e-3, seed: int = 0, method: str = "celora",
         ckpt: str | None = None, verbose: bool = True,
         reduced: bool = False, client_parallelism: str = "vmap",
@@ -86,6 +113,7 @@ def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
         staleness_decay: float = 1.0, latency: str = "uniform",
         latency_scale: float = 1.0, latency_sigma: float = 0.5,
         attn_impl: str | None = None) -> dict:
+    """``arch`` names a registered config or is a :class:`ModelConfig`."""
     if client_parallelism not in ("loop", "vmap"):
         raise ValueError(f"client_parallelism={client_parallelism!r}; "
                          f"expected 'loop' or 'vmap'")
@@ -127,7 +155,8 @@ def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
     if not 0.0 <= straggler_frac < 1.0:
         raise ValueError(f"straggler_frac must be in [0, 1); "
                          f"got {straggler_frac}")
-    cfg = get_config(arch)
+    cfg = arch if isinstance(arch, ModelConfig) else get_config(arch)
+    arch = cfg.name                     # the checkpoint metadata's name
     if reduced:
         cfg = cfg.reduced()
     if attn_impl is not None:
@@ -138,9 +167,7 @@ def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
             raise ValueError(f"attn_impl={attn_impl!r}; "
                              f"expected one of {IMPLS}")
         cfg = cfg.with_overrides(attn_impl=attn_impl)
-    key = jax.random.key(seed)
-    params = model.init_params(cfg, key)
-    base = params["base"]
+    base = model.init_base(cfg, jax.random.key(seed))
 
     # per-client Zipf-Markov LM streams with client-specific transition
     # structure (the non-IID-ness federated personalization feeds on)
@@ -149,7 +176,7 @@ def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
     iters = [synthetic.lm_batches(s, batch, seq, seed=seed + i)
              for i, s in enumerate(streams)]
 
-    adapters = [model.init_params(cfg, jax.random.key(seed + i))["adapter"]
+    adapters = [model.init_adapter(cfg, jax.random.key(seed + i))
                 for i in range(clients)]
     opt = adamw(lr=lr)
 
@@ -161,33 +188,22 @@ def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
     compressed = not codec.is_identity and method in ("celora", "fedavg")
     payload_of = tri_lora.tree_payload if method == "celora" else (lambda t: t)
 
-    def _local_fit(adapter, toks, labs):
-        state = opt.init(adapter)
-
-        def step(carry, b):
-            ad, st = carry
-            (loss, _), g = jax.value_and_grad(
-                lambda a: model.loss_fn(cfg, a, base,
-                                        {"tokens": b[0], "labels": b[1]}),
-                has_aux=True)(ad)
-            upd, st = opt.update(g, st, ad)
-            return (apply_updates(ad, upd), st), loss
-
-        (adapter, _), losses = jax.lax.scan(step, (adapter, state),
-                                            (toks, labs))
-        return adapter, losses
-
-    local_fit = jax.jit(jax.vmap(_local_fit) if vectorized else _local_fit)
+    _local_fit = make_local_fit(cfg, opt)
+    fit = jax.jit(jax.vmap(_local_fit, in_axes=(None, 0, 0, 0))
+                  if vectorized else _local_fit)
     stacked = None
     if vectorized and client_store != "host":
         stacked = client_batch.stack_states(adapters)
         if client_store == "sharded":
             # client axis over the device mesh (DESIGN.md §12): the same
             # stacked programs run under GSPMD with each device owning an
-            # m/d row block
+            # m/d row block and a replica of the frozen base
             from repro.launch import mesh as mesh_lib
-            stacked = mesh_lib.shard_clients(
-                mesh_lib.make_client_mesh(clients), stacked)
+            cmesh = mesh_lib.make_client_mesh(clients)
+            stacked = mesh_lib.shard_clients(cmesh, stacked)
+            base = jax.device_put(base, jax.sharding.NamedSharding(
+                cmesh, jax.sharding.PartitionSpec()))
+    local_fit = functools.partial(fit, base)
 
     def _draw(i):
         bs = [next(iters[i]) for _ in range(local_steps)]
@@ -222,7 +238,7 @@ def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
 
     if engine == "async":
         history, adapters = _run_async_lm(
-            local_fit_raw=_local_fit, draw=_draw, stacked=stacked,
+            local_fit_raw=_local_fit, base=base, draw=_draw, stacked=stacked,
             plans=plans, method=method, clients=clients, rounds=rounds,
             seed=seed, verbose=verbose, codec=codec, compressed=compressed,
             payload_of=payload_of, buffer_size=buffer_size,
@@ -238,16 +254,18 @@ def run(arch: str = "fed-100m", clients: int = 4, rounds: int = 10,
                 "base": base}
 
     if engine == "scan":
-        history, adapters = _run_scan_lm(
-            cfg=cfg, local_fit_raw=_local_fit, draw=_draw,
+        history, stacked = _run_scan_lm(
+            cfg=cfg, local_fit_raw=_local_fit, base=base, draw=_draw,
             stacked=stacked, plans=plans, method=method, clients=clients,
             rounds=rounds, chunk_rounds=chunk_rounds, seed=seed,
             ckpt=ckpt, resume=resume, verbose=verbose,
             codec=codec, compressed=compressed, payload_of=payload_of,
             donate=scan_donate, prefetch=scan_prefetch,
             client_store=client_store)
-        return {"history": history, "adapters": adapters, "cfg": cfg,
-                "base": base}
+        # "stacked" keeps the client axis in its device layout
+        return {"history": history,
+                "adapters": client_batch.unstack_states(stacked),
+                "stacked": stacked, "cfg": cfg, "base": base}
 
     if compressed:
         ef = (compress.init_ef(payload_of(stacked)) if vectorized
@@ -470,8 +488,9 @@ def _run_host_lm(*, local_fit, draw, adapters, plans, method: str,
     return history, store.unstack()
 
 
-def _run_async_lm(*, local_fit_raw, draw, stacked, plans, method: str,
-                  clients: int, rounds: int, seed: int, verbose: bool,
+def _run_async_lm(*, local_fit_raw, base, draw, stacked, plans,
+                  method: str, clients: int, rounds: int, seed: int,
+                  verbose: bool,
                   codec, compressed: bool, payload_of,
                   buffer_size: int, concurrency: int,
                   staleness_decay: float,
@@ -493,7 +512,7 @@ def _run_async_lm(*, local_fit_raw, draw, stacked, plans, method: str,
     decay = float(staleness_decay)
     if not 0.0 < decay <= 1.0:
         raise ValueError(f"staleness_decay must be in (0, 1]; got {decay}")
-    vfit = jax.vmap(local_fit_raw)
+    vfit = jax.vmap(local_fit_raw, in_axes=(None, 0, 0, 0))
     has_payload = method in ("celora", "fedavg")
     if has_payload:
         payload_struct = jax.eval_shape(payload_of, stacked)
@@ -509,9 +528,9 @@ def _run_async_lm(*, local_fit_raw, draw, stacked, plans, method: str,
              "ef": compress.init_ef(payload_of(stacked))
              if compressed else None}
 
-    def _fit(stk, ef, ids, waves, toks, labs):
+    def _fit(base, stk, ef, ids, waves, toks, labs):
         rows = client_batch.gather_clients(stk, ids)
-        new, ls = vfit(rows, toks, labs)
+        new, ls = vfit(base, rows, toks, labs)
         if compressed:
             keys = jax.vmap(lambda w, i: compress.client_key(seed, w, i))(
                 waves, ids)
@@ -569,7 +588,7 @@ def _run_async_lm(*, local_fit_raw, draw, stacked, plans, method: str,
             toks.append(tk)
             labs.append(lb)
         new_stk, new_ef, ls, served = fit_jit(
-            state["stacked"], state["ef"], jnp.asarray(ids, jnp.int32),
+            base, state["stacked"], state["ef"], jnp.asarray(ids, jnp.int32),
             jnp.asarray(wv, jnp.int32), jnp.asarray(np.stack(toks)),
             jnp.asarray(np.stack(labs)))
         state["stacked"], state["ef"] = new_stk, new_ef
@@ -612,8 +631,9 @@ def _run_async_lm(*, local_fit_raw, draw, stacked, plans, method: str,
     return history, client_batch.unstack_states(state["stacked"])
 
 
-def _run_scan_lm(*, cfg, local_fit_raw, draw, stacked, plans, method: str,
-                 clients: int, rounds: int, chunk_rounds: int, seed: int,
+def _run_scan_lm(*, cfg, local_fit_raw, base, draw, stacked, plans,
+                 method: str, clients: int, rounds: int, chunk_rounds: int,
+                 seed: int,
                  ckpt: str | None, resume: bool, verbose: bool,
                  codec=None, compressed: bool = False, payload_of=None,
                  donate: bool = True, prefetch: bool = True,
@@ -630,7 +650,7 @@ def _run_scan_lm(*, cfg, local_fit_raw, draw, stacked, plans, method: str,
     raises), and a background thread draws/stacks the next chunk's batches
     while the current chunk computes."""
     chunk = max(1, int(chunk_rounds))
-    vfit = jax.vmap(local_fit_raw)
+    vfit = jax.vmap(local_fit_raw, in_axes=(None, 0, 0, 0))
     pstack = sampling.stack_plans(plans, clients)
     codec = codec or compress.get_codec("none")
     payload_of = payload_of or (lambda t: t)
@@ -653,10 +673,10 @@ def _run_scan_lm(*, cfg, local_fit_raw, draw, stacked, plans, method: str,
         per_down_b = per_b
     ef = compress.init_ef(payload_of(stacked)) if compressed else {}
 
-    def round_step(carry, xs):
+    def round_step(base, carry, xs):
         stk, ef = carry
         toks, labs, smask, pmask, rnd = xs
-        new, ls = vfit(stk, toks, labs)
+        new, ls = vfit(base, stk, toks, labs)
         stk = client_batch.select_clients(smask, new, stk)
         if compressed:
             _, served, ef_new = compress.encode_stacked(
@@ -680,7 +700,10 @@ def _run_scan_lm(*, cfg, local_fit_raw, draw, stacked, plans, method: str,
         loss = jnp.sum(ls[:, -1] * sm) / jnp.maximum(jnp.sum(sm), 1.0)
         return (stk, ef), loss
 
-    scan_fn = lambda c, xs: jax.lax.scan(round_step, c, xs)
+    def scan_fn(c, xs, base):
+        return jax.lax.scan(functools.partial(round_step, base), c, xs)
+
+    # the carry is donated, the frozen base never is
     run_chunk = (jax.jit(scan_fn, donate_argnums=(0,)) if donate
                  else jax.jit(scan_fn))
 
@@ -739,7 +762,7 @@ def _run_scan_lm(*, cfg, local_fit_raw, draw, stacked, plans, method: str,
               jnp.asarray(pstack.sampled_mask[c0:c1]),
               jnp.asarray(pstack.participant_mask[c0:c1]),
               jnp.arange(c0, c1, dtype=jnp.int32))
-        carry, losses = run_chunk(carry, xs)
+        carry, losses = run_chunk(carry, xs, base)
         return carry, np.asarray(losses)         # one host sync per chunk
 
     def on_chunk(carry, c0, c1, losses, host_s, device_s, wall_s):
@@ -776,7 +799,7 @@ def _run_scan_lm(*, cfg, local_fit_raw, draw, stacked, plans, method: str,
                 "wall_s": hist_wall[rnd],
                 "host_s": hist_host[rnd], "device_s": hist_dev[rnd]}
                for rnd in range(rounds)]
-    return history, client_batch.unstack_states(stacked)
+    return history, stacked
 
 
 def main():
@@ -847,6 +870,7 @@ def main():
                          "device mesh, or host-resident with per-round "
                          "cohort gather/write-back")
     args = ap.parse_args()
+    place_compile_cache()
     out = run(arch=args.arch, clients=args.clients, rounds=args.rounds,
               local_steps=args.local_steps, batch=args.batch, seq=args.seq,
               lr=args.lr, method=args.method, ckpt=args.ckpt,

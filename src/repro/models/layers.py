@@ -46,21 +46,10 @@ def dense(x: jnp.ndarray, w: jnp.ndarray, *, bias: Optional[jnp.ndarray] = None,
 # ---------------------------------------------------------------------------
 
 def _ambient_mesh():
-    """The mesh visible at trace time: the new-style ambient abstract mesh
-    (jax >= 0.5), or the legacy `with mesh:` context-manager mesh."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        am = get_am()
-        if am is not None and am.axis_names:
-            return am
-    try:  # legacy context-manager mesh (what `with mesh:` sets)
-        from jax._src import mesh as _mesh_lib
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except (ImportError, AttributeError):
-        pass  # private-module layout changed across jax versions
-    return None
+    """The mesh visible at trace time (what ``jax.set_mesh`` installs), or
+    None when no mesh with named axes is set."""
+    am = jax.sharding.get_abstract_mesh()
+    return am if am.axis_names else None
 
 
 def shard_hint(x: jnp.ndarray, *spec) -> jnp.ndarray:

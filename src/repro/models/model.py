@@ -33,7 +33,11 @@ def _enc_cfg(cfg: ModelConfig) -> ModelConfig:
 # init
 # ---------------------------------------------------------------------------
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
+@functools.partial(jax.jit, static_argnums=0)
+def init_base(cfg: ModelConfig, key: jax.Array) -> dict:
+    """The frozen backbone, built by one compiled program: XLA writes every
+    layer straight into its stacked output, so the device never holds the
+    per-layer arrays and their stack at once."""
     ks = jax.random.split(key, 8)
     base: dict = {"embed": layers.init_embedding(ks[0], cfg.padded_vocab,
                                                  cfg.d_model, cfg.dtype),
@@ -55,9 +59,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             "pos_embed": (jax.random.normal(
                 ks[4], (cfg.enc_frames, cfg.d_model)) * 0.02).astype(cfg.dtype),
         }
-    ag, at = transformer.init_stack_adapters(ks[5], cfg, cross=cfg.enc_dec)
-    adapter = {"groups": ag, "tail": at}
-    return {"base": base, "adapter": adapter}
+    return base
+
+
+def init_adapter(cfg: ModelConfig, key: jax.Array) -> dict:
+    """The trainable tri-LoRA tree that ``init_params(cfg, key)`` pairs with
+    its base, drawn without building the base."""
+    ag, at = transformer.init_stack_adapters(jax.random.split(key, 8)[5], cfg,
+                                             cross=cfg.enc_dec)
+    return {"groups": ag, "tail": at}
+
+
+def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
+    return {"base": init_base(cfg, key), "adapter": init_adapter(cfg, key)}
 
 
 def abstract_params(cfg: ModelConfig) -> dict:

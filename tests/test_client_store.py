@@ -38,7 +38,7 @@ STORES = client_store.STORE_BACKENDS
 # store contract: gather ∘ scatter round-trips the population exactly
 # ---------------------------------------------------------------------------
 
-_M = 6
+_M = 8                  # divisible by every emulated device count CI uses
 
 
 def _toy_states(m=_M, seed=0):
@@ -239,7 +239,12 @@ def test_host_matches_device_data_similarity(fed_setup):
               feature_samples=64, gmm_components=2)
     ref = _run(fed_setup, "device", "scan", **kw)
     out = _run(fed_setup, "host", "scan", **kw)
-    _assert_history_close(ref, out)
+    # The host store fits the 2 sampled clients, the device store all 4,
+    # so XLA tiles the two vmapped fits differently and their gradients
+    # differ in the last bit; Adam's g / (sqrt(v) + eps) turns that into an
+    # O(lr) = 1e-2 update gap for entries near zero.  Measured on XLA:CPU
+    # (JAX 0.9.0): 1.1e-4 on one host and up to 1.07e-3 on another.
+    _assert_history_close(ref, out, states_atol=5e-3)
 
 
 def test_host_fedavg_matches_device(fed_setup):

@@ -43,6 +43,8 @@ def test_dryrun_single_combo(tmp_path):
 def test_serve_main_cli(monkeypatch, capsys):
     """The serving driver's argparse entry generates end to end."""
     from repro.launch import serve
+    # keep this worker's later compiles out of the persistent cache
+    monkeypatch.setattr(serve, "place_compile_cache", lambda: None)
     monkeypatch.setattr(sys, "argv",
                         ["serve", "--arch", "fed-100m", "--reduced",
                          "--batch", "1", "--prompt-len", "4", "--gen", "2"])

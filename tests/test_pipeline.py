@@ -254,13 +254,26 @@ def test_eval_every_semantics(fed_setup):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
 
 
+# Eager and scan compile the same round into different XLA programs, whose
+# float32 reductions differ in the last bit.  Adam divides each gradient
+# entry by its own running magnitude (g / (sqrt(v) + eps)), so an entry
+# near zero turns that last-bit difference into an O(lr) difference in the
+# update, and the loss gap grows about a hundredfold a round once visible.
+# Measured on XLA:CPU (JAX 0.9.0), rounds 0-3: 1.2e-7, 2.4e-7, 5.4e-6 and
+# 8.7e-4 to 9.9e-4 (by host); with Adam's eps raised to 1e-2 round 3 stays
+# at 1.2e-7.  The bound is 1e-4 through round 2 and five times the worst
+# gap at round 3.
+_EAGER_SCAN_LOSS_BOUND = (1e-4, 1e-4, 1e-4, 5e-3)
+
+
 def test_eval_every_eager_matches_scan(fed_setup):
     """The eager engine honors the same cadence semantics."""
     eager = _run(fed_setup, engine="eager", rounds=4, eval_every=2)
     scan = _run(fed_setup, engine="scan", rounds=4, eval_every=2)
-    for r_e, r_s in zip(eager["history"], scan["history"]):
+    for r_e, r_s, bound in zip(eager["history"], scan["history"],
+                               _EAGER_SCAN_LOSS_BOUND, strict=True):
         assert r_e.evaluated == r_s.evaluated
-        assert abs(r_e.train_loss - r_s.train_loss) < 1e-4
+        assert abs(r_e.train_loss - r_s.train_loss) < bound
         np.testing.assert_allclose(r_e.accs, r_s.accs, atol=1e-3)
 
 
