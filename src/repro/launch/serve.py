@@ -19,6 +19,15 @@ Two inference modes for paper eqn (10)'s per-client adapters:
 * :func:`serve_naive` — the baseline the benchmark beats: per user, merge
   that user's adapter into the base weights (eqn. 10) and decode batch-1,
   sequentially.
+
+``ServeEngine.run`` writes host spans into the profiler's trace whenever
+one is being recorded (``jax.profiler.trace(dir)`` around the call):
+``serve.run``, ``serve.ring_init``, and per loop iteration a
+``serve.step`` holding ``serve.admit``, ``serve.dispatch``, ``serve.sync``
+and ``serve.bookkeep``.  ``serve.run`` carries ``ServeEngine.stats``, the
+slot count and the per-layer KV ring shape as metadata.  The decode step's
+operations carry the named scopes ``kv_ring``, ``tri_lora``, ``attention``
+and ``logits`` in their ``op_name`` metadata.
 """
 from __future__ import annotations
 
@@ -127,7 +136,34 @@ def _serve_step(cfg, base, bank_dec, cache, tok, pos, rows):
     logits, cache = model.decode_step(
         cfg, base, bank_dec, cache, {"token": tok, "positions": positions},
         adapter_rows=rows)
-    return jnp.argmax(logits[:, -1], axis=-1), cache
+    with jax.named_scope("logits"):
+        nxt = jnp.argmax(logits[:, -1], axis=-1)
+    return nxt, cache
+
+
+@dataclasses.dataclass
+class ServeStats:
+    """What one ``ServeEngine.run`` did, counted in its slot loop.  Each
+    slot of each step is exactly one of prefill (fed a prompt token whose
+    output is discarded), emit (its output token was emitted) or empty."""
+    steps: int = 0
+    slot_steps_prefill: int = 0
+    slot_steps_emit: int = 0
+    slot_steps_empty: int = 0
+    admitted: int = 0
+    finished: int = 0
+
+
+def _ring_shapes(cache: dict) -> str:
+    """The per-layer K/V ring shapes of a decode cache, as ``BxWxKxhd``
+    (several joined by ``;``): scanned groups drop their leading layer
+    axis."""
+    out = set()
+    for path, leaf in jax.tree.flatten_with_path(cache)[0]:
+        if getattr(path[-1], "key", None) in ("k", "v"):
+            shape = leaf.shape[1:] if path[0].key == "groups" else leaf.shape
+            out.add("x".join(map(str, shape)))
+    return ";".join(sorted(out))
 
 
 class ServeEngine:
@@ -138,13 +174,26 @@ class ServeEngine:
     its own ring position (ragged ``idx``).  Idle slots carry row/pos -1 —
     the masked-slot sentinel of the grouped kernels.  Greedy decode only:
     the point is bit-replayable equivalence to the per-user oracle.
+
+    ``stats`` holds the counters of the last ``run()`` (reset at each).
+
+    The step's named scopes are metadata, which JAX's persistent cache
+    leaves out of its key unless told otherwise; a step loaded from the
+    cache would then carry the scopes of whichever build compiled it first
+    into every profile.  So constructing an engine makes the process's
+    cache key on metadata.
     """
 
     def __init__(self, cfg, base: dict, bank: AdapterBank, *, slots: int = 8,
                  max_len: int = 128):
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         self.cfg, self.base, self.bank = cfg, base, bank
         self.slots, self.max_len = slots, max_len
         self._bank_dec = bank.decode_tree()
+        self._ring = _ring_shapes(jax.eval_shape(
+            lambda: model.init_decode_cache(cfg, slots, max_len)))
+        self.stats = ServeStats()
 
     def run(self, requests: Sequence[Request],
             progress: bool = False) -> Dict[int, np.ndarray]:
@@ -154,8 +203,19 @@ class ServeEngine:
             if need > self.max_len:
                 raise ValueError(f"request {r.rid} needs {need} positions "
                                  f"> max_len={self.max_len}")
-        queue = list(requests)
-        cache = model.init_decode_cache(self.cfg, self.slots, self.max_len)
+        st = self.stats = ServeStats()
+        with jax.profiler.TraceAnnotation("serve.run") as span:
+            done = self._drain(list(requests), st, progress)
+            span.set_metadata(slots=self.slots, kv_ring=self._ring,
+                              **dataclasses.asdict(st))
+        return done
+
+    def _drain(self, queue: List[Request], st: ServeStats,
+               progress: bool) -> Dict[int, np.ndarray]:
+        n_req = len(queue)
+        with jax.profiler.TraceAnnotation("serve.ring_init"):
+            cache = model.init_decode_cache(self.cfg, self.slots,
+                                            self.max_len)
         active: List[Optional[Request]] = [None] * self.slots
         emitted: Dict[int, List[int]] = {}
         pos = np.full((self.slots,), -1, np.int32)
@@ -164,38 +224,56 @@ class ServeEngine:
         done: Dict[int, np.ndarray] = {}
 
         while queue or any(a is not None for a in active):
-            for s in range(self.slots):       # admit arrivals into free slots
-                if active[s] is None and queue:
-                    r = queue.pop(0)
-                    active[s] = r
-                    emitted[r.rid] = list(r.prompt)
-                    pos[s] = 0                # slot REUSE: ring restarts; the
-                    rows[s] = self.bank.lookup(r.user_id)   # validity mask
-                    tok[s] = int(r.prompt[0])  # (slot <= idx) hides stale KV
-            nxt, cache = _serve_step(self.cfg, self.base, self._bank_dec,
-                                     cache, jnp.asarray(tok[:, None]),
-                                     jnp.asarray(pos), jnp.asarray(rows))
-            nxt = np.asarray(nxt)
-            for s in range(self.slots):
-                r = active[s]
-                if r is None:
-                    continue
-                t = int(pos[s])
-                total = len(r.prompt) + r.gen
-                if t < len(r.prompt) - 1:     # still feeding the prompt
-                    tok[s] = int(r.prompt[t + 1])
-                else:                         # greedy continuation
-                    emitted[r.rid].append(int(nxt[s]))
-                    tok[s] = int(nxt[s])
-                pos[s] += 1
-                if len(emitted[r.rid]) >= total:
-                    done[r.rid] = np.asarray(emitted.pop(r.rid), np.int32)
-                    if progress:
-                        print(f"#   finished rid={r.rid} user={r.user_id} "
-                              f"({len(done)}/{len(requests)})")
-                    active[s] = None          # freed: next arrival reuses it
-                    pos[s], rows[s], tok[s] = -1, -1, 0
+            with jax.profiler.StepTraceAnnotation("serve.step",
+                                                  step_num=st.steps):
+                with jax.profiler.TraceAnnotation("serve.admit"):
+                    for s in range(self.slots):   # arrivals into free slots
+                        if active[s] is None and queue:
+                            r = queue.pop(0)
+                            active[s] = r
+                            emitted[r.rid] = list(r.prompt)
+                            pos[s] = 0        # slot REUSE: ring restarts; the
+                            rows[s] = self.bank.lookup(r.user_id)  # validity
+                            tok[s] = int(r.prompt[0])  # mask (slot <= idx)
+                            st.admitted += 1           # hides stale KV
+                with jax.profiler.TraceAnnotation("serve.dispatch"):
+                    nxt, cache = _serve_step(
+                        self.cfg, self.base, self._bank_dec, cache,
+                        jnp.asarray(tok[:, None]), jnp.asarray(pos),
+                        jnp.asarray(rows))
+                with jax.profiler.TraceAnnotation("serve.sync"):
+                    nxt = np.asarray(nxt)
+                with jax.profiler.TraceAnnotation("serve.bookkeep"):
+                    self._bookkeep(nxt, active, emitted, pos, rows, tok,
+                                   done, st, progress, n_req)
+            st.steps += 1
         return done
+
+    def _bookkeep(self, nxt, active, emitted, pos, rows, tok, done,
+                  st: ServeStats, progress: bool, n_req: int) -> None:
+        for s in range(self.slots):
+            r = active[s]
+            if r is None:
+                st.slot_steps_empty += 1
+                continue
+            t = int(pos[s])
+            total = len(r.prompt) + r.gen
+            if t < len(r.prompt) - 1:     # still feeding the prompt
+                tok[s] = int(r.prompt[t + 1])
+                st.slot_steps_prefill += 1
+            else:                         # greedy continuation
+                emitted[r.rid].append(int(nxt[s]))
+                tok[s] = int(nxt[s])
+                st.slot_steps_emit += 1
+            pos[s] += 1
+            if len(emitted[r.rid]) >= total:
+                done[r.rid] = np.asarray(emitted.pop(r.rid), np.int32)
+                st.finished += 1
+                if progress:
+                    print(f"#   finished rid={r.rid} user={r.user_id} "
+                          f"({len(done)}/{n_req})")
+                active[s] = None          # freed: next arrival reuses it
+                pos[s], rows[s], tok[s] = -1, -1, 0
 
 
 def serve_naive(cfg, base: dict, bank: AdapterBank,

@@ -400,30 +400,33 @@ def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     b = x.shape[0]
     ring = cache["k"].shape[1]
     idx = cache["idx"]                      # absolute position of the new token
-    if jnp.ndim(idx) == 0:
-        slot = jnp.mod(idx, ring)
-        k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
-        new_cache = {"k": k, "v": v, "idx": idx + 1}
-        # validity: slots [0, idx] until the ring wraps, then all slots
-        valid = (jnp.arange(ring)[None, :] <= idx) | (idx >= ring)
-        valid = jnp.broadcast_to(valid, (b, ring))
-    else:                                   # ragged per-row ring positions
-        active = idx >= 0
-        slot = jnp.where(active, jnp.mod(idx, ring), 0)
-        wb = jnp.where(active, jnp.arange(b), b)    # OOB ⇒ dropped write
-        k = cache["k"].at[wb, slot].set(k_new[:, 0].astype(cache["k"].dtype),
-                                        mode="drop")
-        v = cache["v"].at[wb, slot].set(v_new[:, 0].astype(cache["v"].dtype),
-                                        mode="drop")
-        new_cache = {"k": k, "v": v, "idx": jnp.where(active, idx + 1, idx)}
-        valid = (jnp.arange(ring)[None, :] <= idx[:, None]) | \
-            (idx[:, None] >= ring)
+    with jax.named_scope("kv_ring"):      # ring write + validity mask
+        if jnp.ndim(idx) == 0:
+            slot = jnp.mod(idx, ring)
+            k = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
+            v = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
+            new_cache = {"k": k, "v": v, "idx": idx + 1}
+            # validity: slots [0, idx] until the ring wraps, then all slots
+            valid = (jnp.arange(ring)[None, :] <= idx) | (idx >= ring)
+            valid = jnp.broadcast_to(valid, (b, ring))
+        else:                               # ragged per-row ring positions
+            active = idx >= 0
+            slot = jnp.where(active, jnp.mod(idx, ring), 0)
+            wb = jnp.where(active, jnp.arange(b), b)  # OOB ⇒ dropped write
+            k = cache["k"].at[wb, slot].set(
+                k_new[:, 0].astype(cache["k"].dtype), mode="drop")
+            v = cache["v"].at[wb, slot].set(
+                v_new[:, 0].astype(cache["v"].dtype), mode="drop")
+            new_cache = {"k": k, "v": v,
+                         "idx": jnp.where(active, idx + 1, idx)}
+            valid = (jnp.arange(ring)[None, :] <= idx[:, None]) | \
+                (idx[:, None] >= ring)
     impl = select_impl(cfg, q.shape[1], kv_valid=True)   # always "ref":
     assert impl == "ref"                # only sdpa handles validity masks
-    out = sdpa(q, k, v, causal=False, kv_valid=valid)
+    with jax.named_scope("attention"):
+        out = sdpa(q, k, v, causal=False, kv_valid=valid)
     sc = cfg.lora_alpha / cfg.lora_rank
     ad = adapters or {}
     y = layers.dense(out.reshape(b, 1, -1), p["wo"], adapter=ad.get("wo"),

@@ -33,8 +33,9 @@ def dense(x: jnp.ndarray, w: jnp.ndarray, *, bias: Optional[jnp.ndarray] = None,
         y = y + bias
     if adapter is not None:
         if adapter_rows is not None:
-            delta = tri_lora.apply_tri_lora_grouped(x, adapter, lora_scaling,
-                                                    adapter_rows)
+            with jax.named_scope("tri_lora"):
+                delta = tri_lora.apply_tri_lora_grouped(
+                    x, adapter, lora_scaling, adapter_rows)
         else:
             delta = tri_lora.apply_tri_lora(x, adapter, lora_scaling)
         y = y + delta.astype(y.dtype)

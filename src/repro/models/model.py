@@ -223,8 +223,9 @@ def decode_step(cfg: ModelConfig, base: dict, adapter: dict, cache: dict,
         cfg, base["groups"], base["tail"], adapter["groups"], adapter["tail"],
         cache["groups"], cache["tail"], x, positions,
         adapter_rows=adapter_rows)
-    x = layers.norm(x, base["final_norm"], cfg.norm_type)
-    logits = layers.unembed(x, base["embed"], cfg.vocab_size)
-    if not pad_vocab and cfg.padded_vocab != cfg.vocab_size:
-        logits = logits[..., :cfg.vocab_size]
+    with jax.named_scope("logits"):
+        x = layers.norm(x, base["final_norm"], cfg.norm_type)
+        logits = layers.unembed(x, base["embed"], cfg.vocab_size)
+        if not pad_vocab and cfg.padded_vocab != cfg.vocab_size:
+            logits = logits[..., :cfg.vocab_size]
     return logits, {"groups": new_g, "tail": new_t}

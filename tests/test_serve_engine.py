@@ -7,10 +7,16 @@ into W, decode batch-1).  Covered: batch sizes 1 / 2 / odd / full, more
 requests than slots (continuous-batching slot reuse), duplicate users
 inside one batch, and a Hypothesis property that permuting the request
 stream permutes nothing (outputs are keyed by request, not by slot).
+Also what a profile of the engine reads: ``ServeEngine.stats`` counts
+every slot-step exactly, the ``serve.*`` spans reach the profiler's trace,
+and the compiled step carries its named scopes.
 
 Hypothesis is an optional dev dependency (repo convention,
 tests/test_properties.py) — the property test skips on a bare environment.
 """
+import dataclasses
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -100,3 +106,95 @@ def test_request_permutation_property(setup):
             np.testing.assert_array_equal(got[r.rid], baseline[r.rid])
 
     prop()
+
+
+# ---------------------------------------------------------------------------
+# counters, spans and scopes (what a profile of the engine reads)
+# ---------------------------------------------------------------------------
+
+def _ragged_requests(cfg, bank, lens, seed=3):
+    """One request per (prompt length, reply length), users in turn."""
+    rng = np.random.default_rng(seed)
+    users = sorted(bank.users)
+    return [Request(rid=i, user_id=users[i % len(users)],
+                    prompt=rng.integers(0, cfg.vocab_size, p).astype(
+                        np.int32), gen=g)
+            for i, (p, g) in enumerate(lens)]
+
+
+@pytest.mark.parametrize("lens,slots", [
+    ([(3, 4), (1, 2), (5, 1), (2, 3)], 4),            # all admitted at once
+    ([(4, 2), (2, 5), (1, 1), (3, 3), (6, 2)], 2),    # slot reuse, a drain
+])
+def test_stats_count_every_slot_step(setup, lens, slots):
+    cfg, base, bank = setup
+    reqs = _ragged_requests(cfg, bank, lens)
+    eng = ServeEngine(cfg, base, bank, slots=slots, max_len=8)
+    got = eng.run(reqs)
+    _assert_same(reqs, got, serve_naive(cfg, base, bank, reqs))
+    st = eng.stats
+    assert st.slot_steps_emit == sum(g for _, g in lens)
+    assert st.slot_steps_prefill == sum(p - 1 for p, _ in lens)
+    assert (st.slot_steps_prefill + st.slot_steps_emit
+            + st.slot_steps_empty) == slots * st.steps
+    assert st.admitted == st.finished == len(lens)
+    # the longest request alone bounds the step count from below
+    assert st.steps >= max(p + g - 1 for p, g in lens)
+
+
+def test_stats_reset_at_each_run(setup):
+    cfg, base, bank = setup
+    eng = ServeEngine(cfg, base, bank, slots=2, max_len=8)
+    eng.run(_ragged_requests(cfg, bank, [(3, 3), (2, 2), (4, 1)]))
+    first = eng.stats
+    eng.run(_ragged_requests(cfg, bank, [(2, 1)]))
+    assert eng.stats is not first
+    assert (eng.stats.steps, eng.stats.slot_steps_emit,
+            eng.stats.slot_steps_prefill, eng.stats.slot_steps_empty,
+            eng.stats.admitted, eng.stats.finished) == (2, 1, 1, 2, 1, 1)
+
+
+def test_decode_step_carries_named_scopes(setup):
+    """The compiled step's op_name metadata holds the scopes a trace
+    reduction buckets device time by; a refactor that drops one fails."""
+    from repro.launch import serve
+    cfg, base, bank = setup
+    eng = ServeEngine(cfg, base, bank, slots=2, max_len=8)
+    cache = model.init_decode_cache(cfg, 2, 8)
+    ints = np.zeros((2,), np.int32)
+    hlo = serve._serve_step.lower(
+        cfg, base, eng._bank_dec, cache, ints[:, None], ints,
+        ints).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("kv_ring", "tri_lora", "attention", "logits"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    # an executable loaded from the persistent cache keeps its metadata
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+
+
+def test_run_writes_its_spans_into_a_profile(setup, tmp_path):
+    from jax.profiler import ProfileData
+    cfg, base, bank = setup
+    eng = ServeEngine(cfg, base, bank, slots=2, max_len=8)
+    reqs = _ragged_requests(cfg, bank, [(3, 2), (2, 2), (1, 3)])
+    eng.run(reqs)                                       # compile outside
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(reqs)
+    pb = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    seen = {}
+    for plane in ProfileData.from_file(str(pb)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    seen.setdefault(ev.name, []).append(dict(ev.stats))
+    st = eng.stats
+    assert len(seen["serve.run"]) == len(seen["serve.ring_init"]) == 1
+    for name in ("serve.step", "serve.admit", "serve.dispatch",
+                 "serve.sync", "serve.bookkeep"):
+        assert len(seen[name]) == st.steps, name
+    assert [s["step_num"] for s in seen["serve.step"]] == list(
+        range(st.steps))
+    meta = seen["serve.run"][0]
+    assert meta["slots"] == 2 and meta["kv_ring"] == "2x8x2x16"
+    assert {k: meta[k] for k in dataclasses.asdict(st)} == \
+        dataclasses.asdict(st)
