@@ -47,8 +47,9 @@ from repro.models import model
 from repro.models.config import get_config
 
 
-# the weights are arguments of every compiled step, never constants in it
-_decode_step = jax.jit(model.decode_step, static_argnums=0)
+# the weights are arguments of every compiled step, never constants in it;
+# the cache is donated, so a step updates its rings in place
+_decode_step = jax.jit(model.decode_step, static_argnums=0, donate_argnums=3)
 
 
 def generate(cfg, params, prompts: jnp.ndarray, gen: int,
@@ -127,9 +128,10 @@ def _with_positions(cache: dict, pos: jnp.ndarray) -> dict:
     return jax.tree.unflatten(treedef, leaves)
 
 
-@functools.partial(jax.jit, static_argnums=0)
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=3)
 def _serve_step(cfg, base, bank_dec, cache, tok, pos, rows):
-    """One continuous-batching decode step; greedy next token per slot."""
+    """One continuous-batching decode step; greedy next token per slot.
+    The cache is donated: its rings are updated in place."""
     cache = _with_positions(cache, pos)
     positions = (jnp.broadcast_to(pos[:, None, None], (pos.shape[0], 1, 3))
                  if cfg.pos_type == "mrope" else pos[:, None])
@@ -155,7 +157,7 @@ class ServeStats:
 
 
 def _ring_shapes(cache: dict) -> str:
-    """The per-layer K/V ring shapes of a decode cache, as ``BxWxKxhd``
+    """The per-layer K/V ring shapes of a decode cache, as ``BxKxWxhd``
     (several joined by ``;``): scanned groups drop their leading layer
     axis."""
     out = set()

@@ -170,8 +170,8 @@ def cache_specs(tree: Any, mesh: Mesh, cfg: ModelConfig,
             bspec = batch if len(batch) > 1 else batch[0]
         else:
             bspec = None
-        if name in ("k", "v"):            # (B, ring, K, hd): seq → model
-            return P(*lead, bspec, _fits(body[1], mesh, "model"), None, None)
+        if name in ("k", "v"):            # (B, K, ring, hd): seq → model
+            return P(*lead, bspec, None, _fits(body[2], mesh, "model"), None)
         if name in ("xk", "xv"):          # (B, F, H, hd)
             return P(*lead, bspec, None, _fits(body[2], mesh, "model"), None)
         if name == "wkv":                 # (B, H, hd, hd)

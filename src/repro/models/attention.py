@@ -287,23 +287,20 @@ CROSS_TILE_THRESHOLD = 4_194_304
 
 
 def select_impl(cfg: Optional[ModelConfig], seq_len: int, *,
-                impl: Optional[str] = None, kv_len: Optional[int] = None,
-                kv_valid: bool = False) -> str:
+                impl: Optional[str] = None,
+                kv_len: Optional[int] = None) -> str:
     """Resolve the attention backend for one call site.
 
     Precedence: explicit ``impl`` kwarg > ``cfg.attn_impl`` > "auto".  The
     returned name is concrete (never "auto").  ``kv_len`` marks the
-    non-causal cross-attention path (tile above CROSS_TILE_THRESHOLD);
-    ``kv_valid`` marks decode/ring-cache calls whose validity masks only the
-    reference SDPA supports.
+    non-causal cross-attention path (tile above CROSS_TILE_THRESHOLD).
+    Decode attends through :func:`ring_sdpa` and asks no backend.
     """
     chosen = impl if impl is not None else (
         cfg.attn_impl if cfg is not None else "auto")
     if chosen not in IMPLS:
         raise ValueError(
             f"unknown attn_impl {chosen!r}; valid: {', '.join(IMPLS)}")
-    if kv_valid:
-        return "ref"            # only sdpa() takes kv_valid masks
     if kv_len is not None:      # cross-attention: non-causal, Sq != Skv
         if chosen in ("ref", "blockwise"):
             return chosen
@@ -376,18 +373,57 @@ def cross_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
 # decode (one token, ring-buffered KV cache)
 # ---------------------------------------------------------------------------
 
+#: The K/V ring stores each head's vector padded with zeros to a multiple
+#: of this lane width.  At such a width the TPU's default layout of the ring
+#: is row-major, the layout its in-place scatter and the attention dots
+#: share, so no decode step re-lays the ring.  (The padding costs no memory
+#: there: a row-major tile pads the head dim to 128 lanes anyway.)
+RING_LANES = 128
+
+
+def ring_head_dim(hd: int) -> int:
+    return -(-hd // RING_LANES) * RING_LANES
+
+
+def _lane_pad(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def ring_sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+              valid: jnp.ndarray) -> jnp.ndarray:
+    """One query token against head-major rings: q (B,1,H,hd), k/v
+    (B,K,W,ring_head_dim(hd)), valid (B,W).  :func:`sdpa`'s arithmetic:
+    f32 logits (the zero lanes add exact zeros) and softmax, the output
+    cast to q's dtype."""
+    b, _, h, hd = q.shape
+    kh = k.shape[1]
+    qg = _lane_pad(q.reshape(b, kh, h // kh, hd), k.shape[-1])
+    logits = jnp.einsum("bkgd,bksd->bkgs", qg.astype(jnp.float32),
+                        k.astype(jnp.float32)) / jnp.sqrt(hd).astype(jnp.float32)
+    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgs,bksd->bkgd", probs, v.astype(jnp.float32))
+    return out[..., :hd].reshape(b, 1, h, hd).astype(q.dtype)
+
+
 def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                           cache: dict, positions, adapters=None,
                           *, window: int = 0,
-                          adapter_rows: Optional[jnp.ndarray] = None):
-    """x: (B, 1, D).  cache: {'k','v': (B, W, K, hd), 'idx': int32 scalar
-    — or (B,) for RAGGED per-row positions (DESIGN.md §15): each sequence
-    advances independently, and rows at idx -1 are masked batch slots that
-    write nothing and attend to nothing}.
+                          adapter_rows: Optional[jnp.ndarray] = None,
+                          layer: Optional[jnp.ndarray] = None):
+    """x: (B, 1, D).  cache: {'k','v': (B, K, W, ring_head_dim(hd)) head-
+    major rings, 'idx': int32 scalar — or (B,) for RAGGED per-row positions
+    (DESIGN.md §15): each sequence advances independently, and rows at idx
+    -1 are masked batch slots that write nothing and attend to nothing}.
 
     ``W`` is the ring size (== window for SWA blocks, == max_len otherwise).
     Keys are stored post-rope; with rotary embeddings relative offsets are
     preserved, so ring overwrite is safe for windowed attention.
+
+    With ``layer`` (an int32 scalar) 'k'/'v' are a layer scan's stacked
+    (L, B, K, W, ·) rings: the new token is scattered into layer ``layer``
+    in place, attention reads that layer out of the stacked buffer, and the
+    returned cache holds the whole stacked rings.
 
     ``adapter_rows`` switches the q/k/v/o adapters to grouped/bank mode —
     ``adapters`` then carries stacked (m, …) factors per target.
@@ -398,16 +434,14 @@ def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     k_new = _rope(cfg, k_new, positions)
 
     b = x.shape[0]
-    ring = cache["k"].shape[1]
+    ring = cache["k"].shape[-2]
     idx = cache["idx"]                      # absolute position of the new token
+    at = () if layer is None else (layer,)
     with jax.named_scope("kv_ring"):      # ring write + validity mask
         if jnp.ndim(idx) == 0:
             slot = jnp.mod(idx, ring)
-            k = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-            v = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
-            new_cache = {"k": k, "v": v, "idx": idx + 1}
+            wb = jnp.arange(b)
+            new_idx = idx + 1
             # validity: slots [0, idx] until the ring wraps, then all slots
             valid = (jnp.arange(ring)[None, :] <= idx) | (idx >= ring)
             valid = jnp.broadcast_to(valid, (b, ring))
@@ -415,32 +449,40 @@ def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
             active = idx >= 0
             slot = jnp.where(active, jnp.mod(idx, ring), 0)
             wb = jnp.where(active, jnp.arange(b), b)  # OOB ⇒ dropped write
-            k = cache["k"].at[wb, slot].set(
-                k_new[:, 0].astype(cache["k"].dtype), mode="drop")
-            v = cache["v"].at[wb, slot].set(
-                v_new[:, 0].astype(cache["v"].dtype), mode="drop")
-            new_cache = {"k": k, "v": v,
-                         "idx": jnp.where(active, idx + 1, idx)}
+            new_idx = jnp.where(active, idx + 1, idx)
             valid = (jnp.arange(ring)[None, :] <= idx[:, None]) | \
                 (idx[:, None] >= ring)
-    impl = select_impl(cfg, q.shape[1], kv_valid=True)   # always "ref":
-    assert impl == "ref"                # only sdpa handles validity masks
+        # one (K, hd) row per batch row, at [layer, row, :, slot]; with the
+        # heads indexed too each write is one contiguous hd row, so XLA
+        # keeps the ring row-major, the layout the attention dots read
+        kh = cache["k"].shape[-3]
+        where = at + (wb[:, None], jnp.arange(kh)[None, :],
+                      jnp.reshape(slot, (-1, 1)))
+        lanes = cache["k"].shape[-1]
+        k_all = cache["k"].at[where].set(
+            _lane_pad(k_new[:, 0], lanes).astype(cache["k"].dtype),
+            mode="drop")
+        v_all = cache["v"].at[where].set(
+            _lane_pad(v_new[:, 0], lanes).astype(cache["v"].dtype),
+            mode="drop")
     with jax.named_scope("attention"):
-        out = sdpa(q, k, v, causal=False, kv_valid=valid)
+        k, v = (k_all, v_all) if layer is None else (k_all[layer],
+                                                     v_all[layer])
+        out = ring_sdpa(q, k, v, valid)
     sc = cfg.lora_alpha / cfg.lora_rank
     ad = adapters or {}
     y = layers.dense(out.reshape(b, 1, -1), p["wo"], adapter=ad.get("wo"),
                      lora_scaling=sc, adapter_rows=adapter_rows)
-    return y, new_cache
+    return y, {"k": k_all, "v": v_all, "idx": new_idx}
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int, *,
                   window: int = 0, dtype=None) -> dict:
     ring = min(window, seq_len) if window else seq_len
-    kh, hd = cfg.n_kv_heads, cfg.hd
+    kh, lanes = cfg.n_kv_heads, ring_head_dim(cfg.hd)
     dt = dtype or cfg.dtype
     return {
-        "k": jnp.zeros((batch, ring, kh, hd), dt),
-        "v": jnp.zeros((batch, ring, kh, hd), dt),
+        "k": jnp.zeros((batch, kh, ring, lanes), dt),    # head-major
+        "v": jnp.zeros((batch, kh, ring, lanes), dt),
         "idx": jnp.zeros((), jnp.int32),
     }

@@ -198,7 +198,10 @@ def loss_fn(cfg: ModelConfig, adapter: dict, base: dict, batch: dict,
 # decode
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def init_decode_cache(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+    """The decode cache, built by one compiled program: each stacked ring is
+    written straight into its output, never stacked from per-layer ones."""
     g, t = transformer.init_stack_cache(cfg, batch, seq_len,
                                         cross=cfg.enc_dec)
     return {"groups": g, "tail": t}

@@ -174,7 +174,10 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
 
 def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
                  cache: dict, x: jnp.ndarray, positions,
-                 adapter_rows: Optional[jnp.ndarray] = None):
+                 adapter_rows: Optional[jnp.ndarray] = None,
+                 layer: Optional[jnp.ndarray] = None):
+    """``layer``: the scan's group index when ``cache`` holds the stacked
+    K/V rings of an attention block (see :func:`run_stack_decode`)."""
     ad = ad or {}
     nt = cfg.norm_type
     if kind in ("attn", "swa"):
@@ -183,7 +186,7 @@ def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
         y, kv = attention.decode_self_attention(
             cfg, p["attn"], h, {k: cache[k] for k in ("k", "v", "idx")},
             positions, ad.get("attn"), window=window,
-            adapter_rows=adapter_rows)
+            adapter_rows=adapter_rows, layer=layer)
         x = x + y
         new_cache = dict(kv)
         if "xattn" in p:
@@ -311,25 +314,48 @@ def run_stack_decode(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
                      adapter_rows=None):
     """One-token decode through the stack; returns (x, new caches).
 
+    The scan carries every attention block's stacked (q, B, K, W, hd) K/V
+    rings with the group index, so each layer scatters its new token into
+    the stacked ring in place and attention reads its layer straight out of
+    it; ``idx`` and every other state (recurrent states, cross caches) are
+    scanned per group as ``xs``/``ys``.  Tail blocks keep per-block rings.
+
     With ``adapter_rows`` (B,) the adapter trees carry a stacked bank axis
     — groups leaves (q, m, …), tail leaves (m, …), see
     ``adapter_bank.AdapterBank.decode_tree`` — and each batch row applies
     its own bank row (DESIGN.md §15)."""
     pattern = cfg.layer_pattern
+    carried = [str(i) for i, kind in enumerate(pattern)
+               if kind in ("attn", "swa")]
 
-    def group_fn(h, scanned):
+    def group_fn(carry, scanned):
+        h, layer, rings = carry
         gp, gad, gc = scanned
-        new_c = {}
+        rings, new_c = dict(rings), {}
         for i, kind in enumerate(pattern):
-            h, new_c[str(i)] = block_decode(cfg, kind, gp[str(i)], gad[str(i)],
-                                            gc[str(i)], h, positions,
-                                            adapter_rows=adapter_rows)
-        return h, new_c
+            key = str(i)
+            if key in rings:
+                h, c = block_decode(cfg, kind, gp[key], gad[key],
+                                    {**gc[key], **rings[key]}, h, positions,
+                                    adapter_rows=adapter_rows, layer=layer)
+                rings[key] = {"k": c.pop("k"), "v": c.pop("v")}
+            else:
+                h, c = block_decode(cfg, kind, gp[key], gad[key], gc[key], h,
+                                    positions, adapter_rows=adapter_rows)
+            new_c[key] = c
+        return (h, layer + 1, rings), new_c
 
     new_groups_cache = None
     if groups_p is not None:
-        x, new_groups_cache = jax.lax.scan(
-            group_fn, x, (groups_p, groups_ad, groups_cache))
+        rings = {i: {"k": groups_cache[i]["k"], "v": groups_cache[i]["v"]}
+                 for i in carried}
+        rest = {i: ({n: a for n, a in c.items() if n not in ("k", "v")}
+                    if i in rings else c) for i, c in groups_cache.items()}
+        (x, _, rings), new_rest = jax.lax.scan(
+            group_fn, (x, jnp.zeros((), jnp.int32), rings),
+            (groups_p, groups_ad, rest))
+        new_groups_cache = {i: {**c, **rings.get(i, {})}
+                            for i, c in new_rest.items()}
     q, _, rem = cfg.stack_plan()
     new_tail = []
     for i, kind in enumerate(rem):

@@ -73,10 +73,41 @@ def test_cross_attention_crossover_pin():
 
 
 def test_kv_valid_pins_ref(tiny_cfg):
-    """Decode/ring-cache calls need validity masks only sdpa supports."""
-    assert select_impl(None, 1, kv_valid=True) == "ref"
-    cfg = tiny_cfg.with_overrides(attn_impl="flash")
-    assert select_impl(cfg, 1, kv_valid=True) == "ref"
+    """Decode attends through the exact masked reference whatever backend
+    the config names: under 'flash' and 'blockwise' a decode run gives the
+    logits, tokens and KV rings of 'ref' bit for bit, on the scalar-idx
+    path and on the ragged path (rows at -1 masked), with an SWA ring that
+    wraps and an unscanned tail block."""
+    from repro.core import adapter_bank
+    from repro.launch import serve
+    cfg0 = tiny_cfg.with_overrides(n_layers=3, layer_pattern=("attn", "swa"),
+                                   window=2)
+    params = model.init_params(cfg0, jax.random.key(0))
+    bank = adapter_bank.random_bank(cfg0, 2, jax.random.key(1)).decode_tree()
+    toks = np.random.default_rng(0).integers(
+        0, cfg0.vocab_size, (4, 3, 1)).astype(np.int32)
+    step = jax.jit(model.decode_step, static_argnums=0)
+
+    def decode(impl):
+        cfg = cfg0.with_overrides(attn_impl=impl)
+        cache, logits = model.init_decode_cache(cfg, 3, 4), []
+        for t, tok in enumerate(toks):
+            lg, cache = step(cfg, params["base"], params["adapter"], cache,
+                             {"token": tok,
+                              "positions": np.full((3, 1), t, np.int32)})
+            logits.append(lg)
+        ragged, nxt = model.init_decode_cache(cfg, 3, 4), []
+        for t, tok in enumerate(toks):
+            pos = np.asarray([t, -1, t - 1], np.int32)   # row 2 starts late
+            rows = np.where(pos >= 0, [0, 0, 1], -1).astype(np.int32)
+            n, ragged = serve._serve_step(cfg, params["base"], bank, ragged,
+                                          tok, pos, rows)
+            nxt.append(n)
+        return logits, cache, nxt, ragged
+
+    want = decode("ref")
+    for impl in ("flash", "blockwise"):
+        jax.tree.map(np.testing.assert_array_equal, decode(impl), want)
 
 
 def test_impls_registry_is_exhaustive():

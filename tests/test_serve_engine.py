@@ -25,6 +25,7 @@ from repro.core import adapter_bank
 from repro.launch.serve import (Request, ServeEngine, make_requests,
                                 serve_naive)
 from repro.models import model
+from repro.models.config import get_config
 
 N_USERS = 4
 
@@ -82,6 +83,36 @@ def test_engine_rejects_overlong_request(setup):
     eng = ServeEngine(cfg, base, bank, slots=2, max_len=8)
     with pytest.raises(ValueError, match="max_len"):
         eng.run(reqs)
+
+
+@pytest.mark.parametrize("arch", ["tiny", "h2o-danube-3-4b", "qwen2.5-14b"])
+def test_step_donates_its_cache(setup, arch):
+    """Each step consumes the cache it is handed (its rings are written in
+    place) and the cache it returns drives the next step: fed a 3-token
+    prompt step by step it emits what a fresh engine emits.  Danube's ring
+    is cut to 2 slots, so the third token wraps it."""
+    from repro.launch import serve
+    cfg, base, bank = setup
+    if arch != "tiny":
+        cfg = get_config(arch).reduced().with_overrides(window=2)
+        base = model.init_params(cfg, jax.random.key(0))["base"]
+        bank = adapter_bank.random_bank(cfg, N_USERS, jax.random.key(1))
+    reqs = make_requests(bank, 2, prompt_len=3, gen=1,
+                         vocab=cfg.vocab_size, seed=5)
+    want = ServeEngine(cfg, base, bank, slots=2, max_len=4).run(reqs)
+
+    eng = ServeEngine(cfg, base, bank, slots=2, max_len=4)
+    cache = model.init_decode_cache(cfg, 2, 4)
+    rows = np.asarray([bank.lookup(r.user_id) for r in reqs], np.int32)
+    for t in range(3):
+        # the rings; the step installs ``pos`` in place of every ``idx``
+        old = [a for a in jax.tree.leaves(cache) if a.ndim >= 4]
+        tok = np.asarray([[r.prompt[t]] for r in reqs], np.int32)
+        nxt, cache = serve._serve_step(cfg, base, eng._bank_dec, cache, tok,
+                                       np.full((2,), t, np.int32), rows)
+        assert all(a.is_deleted() for a in old)
+    np.testing.assert_array_equal(np.asarray(nxt),
+                                  [want[r.rid][-1] for r in reqs])
 
 
 def test_request_permutation_property(setup):
@@ -195,6 +226,6 @@ def test_run_writes_its_spans_into_a_profile(setup, tmp_path):
     assert [s["step_num"] for s in seen["serve.step"]] == list(
         range(st.steps))
     meta = seen["serve.run"][0]
-    assert meta["slots"] == 2 and meta["kv_ring"] == "2x8x2x16"
+    assert meta["slots"] == 2 and meta["kv_ring"] == "2x2x8x128"
     assert {k: meta[k] for k in dataclasses.asdict(st)} == \
         dataclasses.asdict(st)

@@ -101,3 +101,19 @@ def test_batch_and_cache_specs(arch, shape_name):
         cache = abstract_cache(cfg, shape_name)
         cspecs = shd.cache_specs(cache, mesh, cfg, baxes)
         _check_divisible(cspecs, cache, mesh)
+
+
+def test_cache_specs_shard_the_ring_axis(tiny_cfg):
+    """K/V rings are head-major, (B, K, ring, hd) per layer: the ring axis
+    (index 2) goes to `model`, in the scanned groups' stacked rings and in
+    the unscanned tail's alike."""
+    mesh = MESHES["16x16"]
+    cfg = tiny_cfg.with_overrides(n_layers=3, layer_pattern=("attn", "swa"),
+                                  window=32)
+    cache = jax.eval_shape(lambda: model.init_decode_cache(cfg, 16, 64))
+    specs = shd.cache_specs(cache, mesh, cfg, ("data",))
+    for ring in ("k", "v"):
+        for i in ("0", "1"):                # the attn and the swa block
+            assert specs["groups"][i][ring] == P(None, "data", None,
+                                                 "model", None)
+        assert specs["tail"][0][ring] == P("data", None, "model", None)

@@ -6,11 +6,15 @@ Covered at LLaMA-7B widths (``celora-llama-7b``): the flash-attention
 forward with its logsumexp and its backward, the fused tri-LoRA matmul,
 and the federated trainer's own vmapped local fit at 24 of the model's 32
 layers, which must fit one chip's HBM with the frozen base as an argument.
+At Danube's attention shape: the serving step writes its KV ring in place.
 
 The topology is described inside a module fixture, never at import, and
 the persistent compilation cache is off around these compiles (an entry
 written for a described chip cannot be read back without one).
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -111,3 +115,79 @@ def test_local_fit_fits_one_chip(one_chip):
     base_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(base))
     assert mem.argument_size_in_bytes >= base_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
+
+
+def _ring_traffic(hlo: str, ring_shapes: set):
+    """Ring-shaped results the compiled step materialises in HBM, as
+    (instruction, opcode) pairs, and the number of in-place ring scatters.
+    Instructions inside fused computations are views their fusion reads,
+    not buffers; parameters, tuple plumbing and bitcasts move nothing; a
+    result in memory space 1 is a layer the compiler streams into on-chip
+    memory for a dot, which is that dot's one read of it."""
+    fused, roots, comp = set(), {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            comp = head.group(1)
+        fused.update(re.findall(r" fusion\(.*calls=%([\w.\-]+)", line))
+        root = re.match(r"\s*ROOT %\S+ = \S+ ([\w\-]+)\(", line)
+        if root and comp:
+            roots[comp] = root.group(1)
+    found, scatters, comp = [], 0, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            comp = head.group(1)
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\](\S*) ([\w\-]+)\(",
+                     line)
+        if comp in fused or not m:
+            continue
+        name, dims, layout, op = m.groups()
+        if (tuple(int(d) for d in dims.split(",") if d) not in ring_shapes
+                or "S(1)" in layout):
+            continue
+        if op in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        if op == "fusion" and called and roots.get(called.group(1)) == \
+                "scatter":
+            scatters += 1
+            continue
+        found.append((name, op))
+    return found, scatters
+
+
+def test_serve_step_updates_the_ring_in_place(one_chip):
+    """The serving step at Danube's attention shape (sliding window, 8 KV
+    heads of 120, 2 layers, 8 slots, a ring of 384) writes each new token
+    into the donated stacked ring with one scatter and its attention dots
+    read the ring where it lies: no ring-shaped slice, copy or re-layout,
+    the ring aliased input to output, and less scratch than one layer's
+    ring."""
+    from repro.core.adapter_bank import random_bank
+    from repro.launch import serve
+    from repro.models import model
+    from repro.models.config import get_config
+    cfg = get_config("h2o-danube-3-4b").with_overrides(n_layers=2)
+    slots, ring = 8, 384
+    base = _on(one_chip, jax.eval_shape(
+        lambda: model.init_base(cfg, jax.random.key(0))))
+    bank = _on(one_chip, jax.eval_shape(
+        lambda: random_bank(cfg, 4, jax.random.key(1)).decode_tree()))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: model.init_decode_cache(cfg, slots, ring)))
+    ints = _on(one_chip, jax.ShapeDtypeStruct((slots,), jnp.int32))
+    toks = _on(one_chip, jax.ShapeDtypeStruct((slots, 1), jnp.int32))
+    compiled = serve._serve_step.lower(
+        cfg, base, bank, cache, toks, ints, ints).compile()
+
+    stacked = cache["groups"]["0"]["k"].shape
+    assert stacked == (2, slots, 8, ring, 128)    # head-major, 128 lanes
+    shapes = {stacked, stacked[1:], (1,) + stacked[1:]}
+    found, scatters = _ring_traffic(compiled.as_text(), shapes)
+    assert not found, found
+    assert scatters >= 2                                # k and v
+    mem = compiled.memory_analysis()
+    rings = 2 * math.prod(stacked) * 2                  # k and v, bf16
+    assert mem.alias_size_in_bytes >= rings
+    assert mem.temp_size_in_bytes < math.prod(stacked[1:]) * 2
