@@ -107,6 +107,8 @@ def count_params(cfg: ModelConfig) -> ParamCount:
             tot += t
             act += a
     embed = cfg.padded_vocab * cfg.d_model
+    if not cfg.tie_embeddings:                   # a separate output head
+        embed += cfg.padded_vocab * cfg.d_model
     if cfg.pos_type == "learned":
         embed += cfg.max_target_positions * cfg.d_model
     tot += embed
@@ -146,7 +148,7 @@ def step_flops(cfg: ModelConfig, shape_name: str) -> dict:
     if cfg.vision_patches and sh.kind != "decode":
         s = s + cfg.vision_patches
     matmul_flops_tok = 2 * (pc.active - pc.embed)   # fwd per token
-    lm_head = 2 * cfg.padded_vocab * cfg.d_model    # tied unembed per token
+    lm_head = 2 * cfg.padded_vocab * cfg.d_model    # unembed per token
     attn = sum(_attn_flops_per_layer(cfg, k, s, True) for k in cfg.kinds())
 
     if sh.kind == "train":

@@ -14,6 +14,7 @@ from repro.configs import (  # noqa: F401
     rwkv6_1_6b,
     qwen2_vl_72b,
     recurrentgemma_2b,
+    deepseek_v2_lite,
     paper_models,
 )
 
@@ -28,4 +29,5 @@ ASSIGNED = (
     "rwkv6-1.6b",
     "qwen2-vl-72b",
     "recurrentgemma-2b",
+    "deepseek-v2-lite",
 )

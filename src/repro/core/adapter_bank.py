@@ -36,11 +36,13 @@ from repro.core import tri_lora
 
 def _normalize_tail(tree: dict) -> dict:
     """``ckpt.load_subtree`` rebuilds tuple indices as string dict keys;
-    decode consumes the tail as a tuple again."""
+    decode consumes the tail (and a leading section) as tuples again."""
     out = dict(tree)
-    tail = tree.get("tail", {})
-    if isinstance(tail, dict):
-        out["tail"] = tuple(tail[k] for k in sorted(tail, key=int))
+    for name in ("tail", "lead"):
+        if isinstance(tree.get(name), dict):
+            out[name] = tuple(tree[name][k] for k in sorted(tree[name],
+                                                            key=int))
+    out.setdefault("tail", ())
     if "groups" not in out:
         out["groups"] = None
     return out
@@ -55,9 +57,10 @@ def _adapter_leaves(tree: Any) -> list:
 class AdapterBank:
     """A stacked (m, …) tri-LoRA adapter tree plus the user → row map.
 
-    ``tree`` mirrors the model's adapter structure ({'groups', 'tail'}) with
-    every {A, C, B} leaf carrying a leading client axis: groups leaves are
-    (m, q, …), tail leaves (m, …).
+    ``tree`` mirrors the model's adapter structure ({'groups', 'tail'},
+    and 'lead' for a model with leading dense layers) with every {A, C, B}
+    leaf carrying a leading client axis: groups leaves are (m, q, …), tail
+    and lead leaves (m, …).
     """
 
     tree: dict
@@ -90,14 +93,15 @@ class AdapterBank:
 
     def decode_tree(self) -> dict:
         """Bank view for the batched decode scan: the layer-group axis must
-        LEAD the scanned xs, so groups leaves become (q, m, …); tail leaves
-        stay (m, …)."""
-        out = {"groups": None, "tail": self.tree["tail"]}
+        LEAD the scanned xs, so groups leaves become (q, m, …); tail and
+        lead leaves stay (m, …)."""
+        out = {k: jax.tree.map(jnp.asarray, v)
+               for k, v in self.tree.items() if k != "groups"}
+        out["groups"] = None
         if self.tree.get("groups") is not None:
             out["groups"] = jax.tree.map(
                 lambda x: jnp.swapaxes(jnp.asarray(x), 0, 1),
                 self.tree["groups"])
-        out["tail"] = jax.tree.map(jnp.asarray, out["tail"])
         return out
 
     def merged_base(self, base: dict, i: int, scaling: float) -> dict:
@@ -119,6 +123,8 @@ class AdapterBank:
         if base.get("groups") is not None and row.get("groups") is not None:
             out["groups"] = _merge(base["groups"], row["groups"])
         out["tail"] = _merge(base["tail"], row["tail"])
+        if "lead" in row:
+            out["lead"] = _merge(base["lead"], row["lead"])
         return out
 
 
@@ -192,6 +198,8 @@ def random_bank(cfg, m: int, key: jax.Array,
 
     ag, at = transformer.init_stack_adapters(key, cfg, cross=cfg.enc_dec)
     proto = {"groups": ag, "tail": at}
+    if cfg.n_dense_layers:
+        proto["lead"] = transformer.init_lead_adapters(key, cfg)
     leaves, treedef = jax.tree.flatten(proto, is_leaf=tri_lora.is_adapter)
     out = []
     for j, ad in enumerate(leaves):

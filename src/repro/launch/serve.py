@@ -27,7 +27,8 @@ one is being recorded (``jax.profiler.trace(dir)`` around the call):
 and ``serve.bookkeep``.  ``serve.run`` carries ``ServeEngine.stats``, the
 slot count and the per-layer KV ring shape as metadata.  The decode step's
 operations carry the named scopes ``kv_ring``, ``tri_lora``, ``attention``
-and ``logits`` in their ``op_name`` metadata.
+and ``logits`` in their ``op_name`` metadata; an MLA model's attention
+sub-blocks run under ``mla`` and its expert layers under ``moe``.
 """
 from __future__ import annotations
 
@@ -43,7 +44,7 @@ import numpy as np
 
 from repro.core.adapter_bank import AdapterBank
 from repro.launch.compile_cache import place_compile_cache
-from repro.models import model
+from repro.models import model, transformer
 from repro.models.config import get_config
 
 
@@ -157,12 +158,12 @@ class ServeStats:
 
 
 def _ring_shapes(cache: dict) -> str:
-    """The per-layer K/V ring shapes of a decode cache, as ``BxKxWxhd``
-    (several joined by ``;``): scanned groups drop their leading layer
-    axis."""
+    """The per-layer ring shapes of a decode cache, as ``BxKxWxhd`` for K/V
+    rings and ``BxWxlanes`` for MLA's latent ring (several joined by
+    ``;``): scanned groups drop their leading layer axis."""
     out = set()
     for path, leaf in jax.tree.flatten_with_path(cache)[0]:
-        if getattr(path[-1], "key", None) in ("k", "v"):
+        if getattr(path[-1], "key", None) in transformer.RING_NAMES:
             shape = leaf.shape[1:] if path[0].key == "groups" else leaf.shape
             out.add("x".join(map(str, shape)))
     return ";".join(sorted(out))

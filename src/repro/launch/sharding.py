@@ -5,14 +5,18 @@ Strategy (DESIGN.md §5):
 - frozen base weights shard BOTH ways: input-dim → `data` (FSDP — needed to
   fit 314B frozen params in 256×16 GB), output-dim → `model` (Megatron TP);
   "out-projections" (wo, w_down, w_out, channel-mix wv) transpose that.
-- embeddings (V, D): V → `model` (sharded logits/softmax), D → `data`.
+- embeddings and an untied `lm_head` (V, D): V → `model` (sharded
+  logits/softmax), D → `data`.
 - MoE experts: expert axis → `model` when divisible (expert parallelism),
   else tensor-parallel inside each expert.
 - tri-LoRA: A in-dim → `data`, B out-dim → `model`, C REPLICATED — C is the
   federated payload; keeping it replicated makes the cross-pod collective
   exactly the paper's r² traffic.
 - KV caches: batch → `data` (+`pod`), cache sequence → `model`
-  (flash-decoding style partial softmax, combined by GSPMD collectives).
+  (flash-decoding style partial softmax, combined by GSPMD collectives);
+  MLA's latent ring (B, ring, lanes) likewise.
+- MLA weights (wq, wkv_a, wkv_b) are in→out matrices and wo an
+  out-projection; shared experts are a dense MLP, not expert-sharded.
 - every rule degrades to replication when the dim is not divisible by the
   mesh axis (e.g. whisper's 12 heads vs model=16).
 
@@ -28,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.models import transformer
 from repro.models.config import ModelConfig
 
 # parameter names whose matrix maps "wide → d_model" (shard in-dim on model)
@@ -68,7 +73,7 @@ def param_spec(path_names: tuple[str, ...], shape: tuple[int, ...],
         return P(*(None,) * len(shape))          # replicated: the payload
 
     # ---- embeddings
-    if name == "embed":
+    if name in ("embed", "lm_head"):
         return P(_fits(shape[0], mesh, "model"), _fits(shape[1], mesh, da))
     if name == "pos_embed":
         return P(None, _fits(shape[1], mesh, "model"))
@@ -118,7 +123,8 @@ def param_spec(path_names: tuple[str, ...], shape: tuple[int, ...],
 
 
 def _is_moe_leaf(path_names, shape, cfg) -> bool:
-    return cfg.is_moe and "moe" in path_names
+    """A routed expert tensor; shared experts are a dense MLP."""
+    return cfg.is_moe and "moe" in path_names and "shared" not in path_names
 
 
 # ---------------------------------------------------------------------------
@@ -170,8 +176,10 @@ def cache_specs(tree: Any, mesh: Mesh, cfg: ModelConfig,
             bspec = batch if len(batch) > 1 else batch[0]
         else:
             bspec = None
-        if name in ("k", "v"):            # (B, K, ring, hd): seq → model
-            return P(*lead, bspec, None, _fits(body[2], mesh, "model"), None)
+        if name in transformer.RING_NAMES:
+            # (B, K, ring, hd) or (B, ring, lanes): ring axis → model
+            mid = (None,) * (len(body) - 3)
+            return P(*lead, bspec, *mid, _fits(body[-2], mesh, "model"), None)
         if name in ("xk", "xv"):          # (B, F, H, hd)
             return P(*lead, bspec, None, _fits(body[2], mesh, "model"), None)
         if name == "wkv":                 # (B, H, hd, hd)
@@ -186,8 +194,8 @@ def cache_specs(tree: Any, mesh: Mesh, cfg: ModelConfig,
     return jax.tree.map_with_path(spec, tree)
 
 
-_CACHE_RANKS = {"k": 4, "v": 4, "xk": 4, "xv": 4, "wkv": 4, "shift": 2,
-                "conv": 3, "h": 2, "idx": 0}
+_CACHE_RANKS = {"k": 4, "v": 4, "latent": 3, "xk": 4, "xv": 4, "wkv": 4,
+                "shift": 2, "conv": 3, "h": 2, "idx": 0}
 
 
 def _cache_rank(name: str) -> int:

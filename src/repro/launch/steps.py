@@ -157,7 +157,8 @@ def make_prefill_step(cfg: ModelConfig,
             attn_impl=attn_impl)
         last = hidden[:, -1]                       # serving: next-token logits
         from repro.models import layers
-        return layers.unembed(last, params["base"]["embed"], cfg.vocab_size)
+        return layers.unembed(last, model.head_table(cfg, params["base"]),
+                              cfg.vocab_size)
     return prefill_step
 
 

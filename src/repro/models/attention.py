@@ -329,6 +329,18 @@ def self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray, positions,
     q, k, v = _project_qkv(cfg, p, x, adapters)
     q = _rope(cfg, q, positions)
     k = _rope(cfg, k, positions)
+    out = _causal_attend(cfg, q, k, v, window=window, impl=impl)
+    b, s = x.shape[:2]
+    sc = cfg.lora_alpha / cfg.lora_rank
+    ad = adapters or {}
+    return layers.dense(out.reshape(b, s, -1), p["wo"], adapter=ad.get("wo"),
+                        lora_scaling=sc)
+
+
+def _causal_attend(cfg: ModelConfig, q, k, v, *, window: int = 0,
+                   impl: Optional[str] = None) -> jnp.ndarray:
+    """Causal attention of q (B,S,H,hd) over k/v (B,S,K,hd) through the
+    backend :func:`select_impl` resolves."""
     impl = select_impl(cfg, q.shape[1], impl=impl)
     if impl == "flash":
         from repro.kernels.flash_attention import ops as fa_ops
@@ -346,11 +358,7 @@ def self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray, positions,
         out = blockwise_sdpa(q, k, v, causal=True, window=window)
     else:
         out = sdpa(q, k, v, causal=True, window=window)
-    b, s = x.shape[:2]
-    sc = cfg.lora_alpha / cfg.lora_rank
-    ad = adapters or {}
-    return layers.dense(out.reshape(b, s, -1), p["wo"], adapter=ad.get("wo"),
-                        lora_scaling=sc)
+    return out
 
 
 def cross_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
@@ -406,6 +414,25 @@ def ring_sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out[..., :hd].reshape(b, 1, h, hd).astype(q.dtype)
 
 
+def _ring_position(idx, ring: int, b: int):
+    """Where a decode step writes and what it may read.  ``idx`` is the new
+    token's absolute position: a scalar, or (B,) ragged per-row positions
+    where -1 marks a masked batch slot.  Returns the ring slot, the batch
+    row to write (``b``, out of bounds, for a masked slot, so its write is
+    dropped), the next ``idx`` and the (B, ring) validity mask: slots
+    [0, idx] until the ring wraps, then all of them."""
+    if jnp.ndim(idx) == 0:
+        valid = (jnp.arange(ring)[None, :] <= idx) | (idx >= ring)
+        return (jnp.mod(idx, ring), jnp.arange(b), idx + 1,
+                jnp.broadcast_to(valid, (b, ring)))
+    active = idx >= 0
+    valid = (jnp.arange(ring)[None, :] <= idx[:, None]) | \
+        (idx[:, None] >= ring)
+    return (jnp.where(active, jnp.mod(idx, ring), 0),
+            jnp.where(active, jnp.arange(b), b),
+            jnp.where(active, idx + 1, idx), valid)
+
+
 def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                           cache: dict, positions, adapters=None,
                           *, window: int = 0,
@@ -435,23 +462,9 @@ def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
 
     b = x.shape[0]
     ring = cache["k"].shape[-2]
-    idx = cache["idx"]                      # absolute position of the new token
     at = () if layer is None else (layer,)
     with jax.named_scope("kv_ring"):      # ring write + validity mask
-        if jnp.ndim(idx) == 0:
-            slot = jnp.mod(idx, ring)
-            wb = jnp.arange(b)
-            new_idx = idx + 1
-            # validity: slots [0, idx] until the ring wraps, then all slots
-            valid = (jnp.arange(ring)[None, :] <= idx) | (idx >= ring)
-            valid = jnp.broadcast_to(valid, (b, ring))
-        else:                               # ragged per-row ring positions
-            active = idx >= 0
-            slot = jnp.where(active, jnp.mod(idx, ring), 0)
-            wb = jnp.where(active, jnp.arange(b), b)  # OOB ⇒ dropped write
-            new_idx = jnp.where(active, idx + 1, idx)
-            valid = (jnp.arange(ring)[None, :] <= idx[:, None]) | \
-                (idx[:, None] >= ring)
+        slot, wb, new_idx, valid = _ring_position(cache["idx"], ring, b)
         # one (K, hd) row per batch row, at [layer, row, :, slot]; with the
         # heads indexed too each write is one contiguous hd row, so XLA
         # keeps the ring row-major, the layout the attention dots read
@@ -486,3 +499,147 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int, *,
         "v": jnp.zeros((batch, kh, ring, lanes), dt),
         "idx": jnp.zeros((), jnp.int32),
     }
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+# ---------------------------------------------------------------------------
+#
+# q = x Wq = [q_nope | q_pe] per head;  [c | k_pe] = x Wkv_a;  c <- RMSNorm(c);
+# [k_nope | v]_h = c Wkv_b,h;  q_pe and the one k_pe all heads share are
+# rotated (rotate-half pairing, YaRN frequencies);  scores
+# (q_nope.k_nope + q_pe.k_pe) * mla_softmax_scale;  y = concat_h(p v) Wo.
+# Decode keeps only [c | k_pe] per token in its ring and absorbs Wkv_b into
+# the query and the output (DESIGN.md §17).
+
+def init_mla(key, cfg: ModelConfig) -> dict:
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    qd = cfg.qk_nope_dim + cfg.qk_rope_dim
+    ks = jax.random.split(key, 4)
+
+    def w(k, din, dout):
+        return (jax.random.normal(k, (din, dout))
+                * (1.0 / jnp.sqrt(din))).astype(cfg.dtype)
+    return {"wq": w(ks[0], d, h * qd),
+            "wkv_a": w(ks[1], d, r + cfg.qk_rope_dim),
+            "kv_norm": {"scale": jnp.zeros((r,), cfg.dtype)},
+            "wkv_b": w(ks[2], r, h * (cfg.qk_nope_dim + cfg.v_head_dim)),
+            "wo": w(ks[3], h * cfg.v_head_dim, d)}
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale(factor, mscale_all_dim)^2."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= layers.yarn_get_mscale(cfg.yarn_factor,
+                                        cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, x: jnp.ndarray, positions) -> jnp.ndarray:
+    """Rotate x (B, S, H, qk_rope_dim); with YaRN the rotated vector is
+    scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    if not cfg.yarn_factor:
+        return layers.apply_rope(x, positions, cfg.rope_theta)
+    inv = layers.yarn_inv_freq(cfg.qk_rope_dim, cfg.rope_theta,
+                               cfg.yarn_factor, cfg.yarn_original_max,
+                               cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    out = layers.apply_rope(x, positions, cfg.rope_theta, inv_freq=inv)
+    gain = (layers.yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+            / layers.yarn_get_mscale(cfg.yarn_factor,
+                                     cfg.yarn_mscale_all_dim))
+    return out if gain == 1.0 else (out * gain).astype(out.dtype)
+
+
+def _mla_project(cfg: ModelConfig, p: dict, x: jnp.ndarray, positions,
+                 adapters, adapter_rows=None):
+    """q_nope (B,S,H,nope), rotated q_pe (B,S,H,rope), the normalised
+    latent c (B,S,r) and the rotated shared key k_pe (B,S,rope)."""
+    ad = adapters or {}
+    kw = dict(lora_scaling=cfg.lora_alpha / cfg.lora_rank,
+              adapter_rows=adapter_rows)
+    b, s, _ = x.shape
+    nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
+    q = layers.dense(x, p["wq"], adapter=ad.get("wq"), **kw).reshape(
+        b, s, cfg.n_heads, nope + cfg.qk_rope_dim)
+    kv = layers.dense(x, p["wkv_a"], adapter=ad.get("wkv_a"), **kw)
+    c = layers.rmsnorm(kv[..., :r], p["kv_norm"]["scale"])
+    q_pe = _mla_rope(cfg, q[..., nope:], positions)
+    k_pe = _mla_rope(cfg, kv[:, :, None, r:], positions)[:, :, 0]
+    return q[..., :nope], q_pe, c, k_pe
+
+
+def mla_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray, positions,
+                  adapters=None, *, impl: Optional[str] = None) -> jnp.ndarray:
+    """Full-sequence causal MLA with k and v computed explicitly.  v is
+    zero-padded to the qk head dim and q pre-multiplied by the YaRN factor,
+    so every backend of :func:`select_impl` applies unchanged."""
+    b, s, _ = x.shape
+    h, nope, vd = cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim
+    qd = nope + cfg.qk_rope_dim
+    q_nope, q_pe, c, k_pe = _mla_project(cfg, p, x, positions, adapters)
+    kv = (c @ p["wkv_b"]).reshape(b, s, h, nope + vd)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe[:, :, None],
+                                          (b, s, h, cfg.qk_rope_dim))], -1)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    q = (q.astype(jnp.float32) * (mla_softmax_scale(cfg) * qd ** 0.5)
+         ).astype(q.dtype)
+    out = _causal_attend(cfg, q, k, _lane_pad(kv[..., nope:], qd), impl=impl)
+    ad = adapters or {}
+    return layers.dense(out[..., :vd].reshape(b, s, h * vd), p["wo"],
+                        adapter=ad.get("wo"),
+                        lora_scaling=cfg.lora_alpha / cfg.lora_rank)
+
+
+def latent_lanes(cfg: ModelConfig) -> int:
+    return ring_head_dim(cfg.kv_lora_rank + cfg.qk_rope_dim)
+
+
+def init_latent_cache(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+    """One ring row per token: [c | k_pe | zero lanes], 576 values in 640
+    lanes at DeepSeek-V2's widths."""
+    return {"latent": jnp.zeros((batch, seq_len, latent_lanes(cfg)),
+                                cfg.dtype),
+            "idx": jnp.zeros((), jnp.int32)}
+
+
+def decode_mla(cfg: ModelConfig, p: dict, x: jnp.ndarray, cache: dict,
+               positions, adapters=None, *,
+               adapter_rows: Optional[jnp.ndarray] = None,
+               layer: Optional[jnp.ndarray] = None):
+    """One token of MLA against the latent ring, Wkv_b absorbed:
+    q_lat,h = W_UK,h q_nope,h scores against c and q_pe against k_pe, and
+    o_h = W_UV,h^T (sum_s p c).  cache: {'latent': (B, W, lanes), or a
+    layer scan's stacked (L, B, W, lanes) with ``layer``; 'idx'} — the
+    ring's write, mask and ragged ``idx`` follow
+    :func:`decode_self_attention`."""
+    b = x.shape[0]
+    h, nope, vd, r = (cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim,
+                      cfg.kv_lora_rank)
+    q_nope, q_pe, c, k_pe = _mla_project(cfg, p, x, positions, adapters,
+                                         adapter_rows)
+    ring, lanes = cache["latent"].shape[-2:]
+    at = () if layer is None else (layer,)
+    with jax.named_scope("kv_ring"):
+        slot, wb, new_idx, valid = _ring_position(cache["idx"], ring, b)
+        row = _lane_pad(jnp.concatenate([c[:, 0], k_pe[:, 0]], -1), lanes)
+        lat = cache["latent"].at[at + (wb, slot)].set(
+            row.astype(cache["latent"].dtype), mode="drop")
+    with jax.named_scope("attention"):
+        kv = (lat if layer is None else lat[layer]).astype(jnp.float32)
+        w = p["wkv_b"].reshape(r, h, nope + vd).astype(jnp.float32)
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(jnp.float32),
+                           w[..., :nope])
+        qf = _lane_pad(jnp.concatenate(
+            [q_lat, q_pe[:, 0].astype(jnp.float32)], -1), lanes)
+        logits = jnp.einsum("bhd,bsd->bhs", qf, kv) * mla_softmax_scale(cfg)
+        probs = jax.nn.softmax(
+            jnp.where(valid[:, None, :], logits, NEG_INF), axis=-1)
+        o_lat = jnp.einsum("bhs,bsd->bhd", probs, kv)[..., :r]
+        o = jnp.einsum("bhc,chv->bhv", o_lat, w[..., nope:])
+    y = layers.dense(o.reshape(b, 1, h * vd).astype(x.dtype), p["wo"],
+                     adapter=(adapters or {}).get("wo"),
+                     lora_scaling=cfg.lora_alpha / cfg.lora_rank,
+                     adapter_rows=adapter_rows)
+    return y, {"latent": lat, "idx": new_idx}

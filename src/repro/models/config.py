@@ -12,6 +12,12 @@ layers.  Kinds:
 - ``swa``    : sliding-window attention block (``window`` controls size)
 - ``rwkv6``  : RWKV-6 "Finch" time-mix + channel-mix (attention-free)
 - ``rglru``  : RG-LRU recurrent block (RecurrentGemma)
+- ``mla``    : multi-head latent attention block (DeepSeek-V2), whose decode
+               cache is one ring of compressed latents and shared rotary keys
+
+``n_dense_layers`` leading layers (DeepSeek's ``first_k_dense_replace``) run
+unrolled before the scanned groups, with a dense MLP of width ``d_ff`` even
+in an MoE model; the pattern then tiles the remaining layers.
 """
 from __future__ import annotations
 
@@ -22,7 +28,7 @@ import jax.numpy as jnp
 
 _DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
-BlockKind = str  # attn | swa | rwkv6 | rglru
+BlockKind = str  # attn | swa | rwkv6 | rglru | mla
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +56,24 @@ class ModelConfig:
     attn_impl: str = "auto"           # attention backend (attention.IMPLS);
     #                                   resolved per call via select_impl()
 
+    # --- multi-head latent attention (``mla`` blocks) ----------------------
+    kv_lora_rank: int = 0             # width of the compressed KV latent
+    qk_nope_dim: int = 0              # per-head query/key width without rope
+    qk_rope_dim: int = 0              # rotary width, one key shared by heads
+    v_head_dim: int = 0               # per-head value width
+    # YaRN scaling of the rotary frequencies (arXiv:2309.00071); factor 0
+    # leaves them unscaled
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 4096     # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    # --- output head -------------------------------------------------------
+    tie_embeddings: bool = True       # unembed through the embedding table;
+    #                                   False gives a separate ``lm_head``
+
     # --- MLP / norm --------------------------------------------------------
     mlp_type: str = "swiglu"          # swiglu | gelu
     norm_type: str = "rmsnorm"        # rmsnorm | layernorm
@@ -59,6 +83,11 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    moe_d_ff: int = 0                 # routed expert width (0 -> d_ff)
+    shared_d_ff: int = 0              # width of the shared experts (0: none)
+    norm_topk: bool = True            # renormalise the top-k gate weights
+    experts_held: int = 0             # experts [0, held) live here (0: all)
+    n_dense_layers: int = 0           # leading layers with a dense MLP
 
     # --- recurrent (rwkv6 / rglru) ----------------------------------------
     rnn_width: int = 0                # 0 -> d_model
@@ -107,16 +136,31 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def n_held(self) -> int:
+        """Routed experts this device holds: experts [0, n_held)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def lead_kinds(self) -> tuple[BlockKind, ...]:
+        """Kinds of the unrolled leading dense layers."""
+        return self.layer_pattern[:1] * self.n_dense_layers
+
     def stack_plan(self) -> tuple[int, tuple[BlockKind, ...], tuple[BlockKind, ...]]:
-        """Return (n_scan_groups, pattern, remainder_kinds)."""
+        """Return (n_scan_groups, pattern, remainder_kinds) of the layers
+        after the leading dense ones."""
         p = len(self.layer_pattern)
-        q, rem = divmod(self.n_layers, p)
+        q, rem = divmod(self.n_layers - self.n_dense_layers, p)
         return q, self.layer_pattern, self.layer_pattern[:rem]
 
     def kinds(self) -> tuple[BlockKind, ...]:
         """Flat per-layer kind list (length n_layers)."""
         q, pat, rem = self.stack_plan()
-        return pat * q + rem
+        return self.lead_kinds + pat * q + rem
 
     def with_overrides(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -125,7 +169,7 @@ class ModelConfig:
         """Smoke-test variant of the same family: tiny but structurally equal."""
         pat = self.layer_pattern
         base = dict(
-            n_layers=max(2, len(pat)),
+            n_layers=max(2, len(pat)) + self.n_dense_layers,
             d_model=256,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads < self.n_heads else 4,
@@ -134,6 +178,9 @@ class ModelConfig:
             vocab_size=512,
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            experts_held=(max(1, self.experts_held * min(self.n_experts, 4)
+                              // self.n_experts)
+                          if self.experts_held else 0),
             n_enc_layers=2 if self.enc_dec else 0,
             enc_frames=16 if self.enc_dec else self.enc_frames,
             vision_patches=16 if self.vision_patches else 0,
@@ -145,6 +192,14 @@ class ModelConfig:
             param_dtype="float32",
             name=self.name + "-reduced",
         )
+        if self.kv_lora_rank:
+            # latent + rotary key fill exactly one 128-lane ring row
+            base.update(kv_lora_rank=96, qk_rope_dim=32, qk_nope_dim=64,
+                        v_head_dim=64)
+        if self.moe_d_ff:
+            base["moe_d_ff"] = 128
+        if self.shared_d_ff:
+            base["shared_d_ff"] = 256
         if self.pos_type == "mrope":
             half = base["head_dim"] // 2
             hw = 3 * half // 8

@@ -10,6 +10,7 @@ Conventions
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -192,12 +193,47 @@ def _mrope_angles(positions: jnp.ndarray, head_dim: int, theta: float,
     return pos * inv_freq
 
 
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor 0.1 m ln(s) + 1 (1 for s <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_max: int,
+                          beta_fast: float, beta_slow: float) -> tuple:
+    """The first and last frequency index of YaRN's ramp: where the
+    frequencies turn ``beta_fast`` and ``beta_slow`` times over the original
+    context."""
+    def at(rot):
+        return (dim * math.log(original_max / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(at(beta_fast)), 0),
+            min(math.ceil(at(beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> jnp.ndarray:
+    """YaRN's (dim/2,) inverse frequencies: ``theta ** (-2i/dim)`` below the
+    ramp, the same divided by ``factor`` above it, linear in between
+    (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    half = dim // 2
+    extra = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    low, high = yarn_correction_range(dim, theta, original_max, beta_fast,
+                                      beta_slow)
+    high = high + 0.001 if low == high else high
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               *, sections=None) -> jnp.ndarray:
-    """x: (B, S, H, hd); positions: (B, S) or (B, S, 3) for M-RoPE."""
+               *, sections=None, inv_freq=None) -> jnp.ndarray:
+    """x: (B, S, H, hd); positions: (B, S) or (B, S, 3) for M-RoPE.
+    ``inv_freq`` (hd/2,) replaces the plain frequencies (YaRN)."""
     hd = x.shape[-1]
     if sections is not None:
         ang = _mrope_angles(positions, hd, theta, sections)   # (B,S,half)
+    elif inv_freq is not None:
+        ang = positions.astype(jnp.float32)[..., None] * inv_freq
     else:
         ang = _rope_angles(positions, hd, theta)              # (B,S,half)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
@@ -255,8 +291,9 @@ def embed(tokens: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
 
 def unembed(x: jnp.ndarray, table: jnp.ndarray,
             true_vocab: int = 0) -> jnp.ndarray:
-    """Tied LM head; logits in f32.  If the table is padded beyond
-    ``true_vocab``, pad logits are masked to -inf (softmax-exact)."""
+    """LM head through a (V, D) table; logits in f32.  If the table is
+    padded beyond ``true_vocab``, pad logits are masked to -inf
+    (softmax-exact)."""
     x = batch_hint(x)
     # keep operands in param dtype; accumulate f32 on the MXU — avoids
     # materializing (and GSPMD gathering) an f32 copy of the vocab table

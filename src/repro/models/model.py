@@ -43,8 +43,14 @@ def init_base(cfg: ModelConfig, key: jax.Array) -> dict:
                                                  cfg.d_model, cfg.dtype),
                   "final_norm": layers.init_norm(cfg.d_model, cfg.norm_type,
                                                  cfg.dtype)}
+    if not cfg.tie_embeddings:
+        base["lm_head"] = layers.init_embedding(
+            jax.random.fold_in(ks[0], 1), cfg.padded_vocab, cfg.d_model,
+            cfg.dtype)
     groups, tail = transformer.init_stack(ks[1], cfg, cross=cfg.enc_dec)
     base["groups"], base["tail"] = groups, tail
+    if cfg.n_dense_layers:
+        base["lead"] = transformer.init_lead(ks[6], cfg)
     if cfg.pos_type == "learned":
         base["pos_embed"] = (jax.random.normal(
             ks[2], (cfg.max_target_positions, cfg.d_model)) * 0.02
@@ -62,12 +68,21 @@ def init_base(cfg: ModelConfig, key: jax.Array) -> dict:
     return base
 
 
+def head_table(cfg: ModelConfig, base: dict) -> jnp.ndarray:
+    """The (V, D) table the logits are read through: the embedding table
+    when tied, else the model's own ``lm_head``."""
+    return base["embed" if cfg.tie_embeddings else "lm_head"]
+
+
 def init_adapter(cfg: ModelConfig, key: jax.Array) -> dict:
     """The trainable tri-LoRA tree that ``init_params(cfg, key)`` pairs with
     its base, drawn without building the base."""
-    ag, at = transformer.init_stack_adapters(jax.random.split(key, 8)[5], cfg,
-                                             cross=cfg.enc_dec)
-    return {"groups": ag, "tail": at}
+    ks = jax.random.split(key, 8)
+    ag, at = transformer.init_stack_adapters(ks[5], cfg, cross=cfg.enc_dec)
+    out = {"groups": ag, "tail": at}
+    if cfg.n_dense_layers:
+        out["lead"] = transformer.init_lead_adapters(ks[7], cfg)
+    return out
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
@@ -128,10 +143,16 @@ def forward_hidden(cfg: ModelConfig, base: dict, adapter: dict, batch: dict,
     enc_out = None
     if cfg.enc_dec:
         enc_out = encode(cfg, base, batch["frames"])
+    lead_aux = jnp.zeros((), jnp.float32)
+    if cfg.n_dense_layers:
+        x, lead_aux = transformer.run_lead(cfg, base["lead"],
+                                           adapter.get("lead"), x, positions,
+                                           attn_impl=attn_impl)
     x, aux = transformer.run_stack(
         cfg, base["groups"], base["tail"], adapter["groups"], adapter["tail"],
         x, positions, enc_out=enc_out, causal=True, attn_impl=attn_impl,
         use_rwkv_kernel=use_rwkv_kernel)
+    aux = aux + lead_aux
     x = layers.norm(x, base["final_norm"], cfg.norm_type)
     return layers.batch_hint(x), aux, n_prefix
 
@@ -144,7 +165,7 @@ def forward(cfg: ModelConfig, base: dict, adapter: dict, batch: dict,
     x, aux, n_prefix = forward_hidden(cfg, base, adapter, batch, **kw)
     if n_prefix:
         x = x[:, n_prefix:]
-    logits = layers.unembed(x, base["embed"], cfg.vocab_size)
+    logits = layers.unembed(x, head_table(cfg, base), cfg.vocab_size)
     if not pad_vocab and cfg.padded_vocab != cfg.vocab_size:
         logits = logits[..., :cfg.vocab_size]
     return logits, aux
@@ -176,7 +197,7 @@ def loss_fn(cfg: ModelConfig, adapter: dict, base: dict, batch: dict,
     if n_prefix:
         hidden = hidden[:, n_prefix:]
     labels = batch["labels"]
-    table = base["embed"]
+    table = head_table(cfg, base)
     s = hidden.shape[1]
     if s * cfg.padded_vocab > _CE_CHUNK_THRESHOLD and s % _CE_CHUNK == 0:
         n = s // _CE_CHUNK
@@ -204,7 +225,10 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
     written straight into its output, never stacked from per-layer ones."""
     g, t = transformer.init_stack_cache(cfg, batch, seq_len,
                                         cross=cfg.enc_dec)
-    return {"groups": g, "tail": t}
+    out = {"groups": g, "tail": t}
+    if cfg.n_dense_layers:
+        out["lead"] = transformer.init_lead_cache(cfg, batch, seq_len)
+    return out
 
 
 def decode_step(cfg: ModelConfig, base: dict, adapter: dict, cache: dict,
@@ -222,13 +246,18 @@ def decode_step(cfg: ModelConfig, base: dict, adapter: dict, cache: dict,
     if cfg.pos_type == "learned":
         pos_idx = positions if positions.ndim == 2 else positions[..., 0]
         x = x + jnp.take(base["pos_embed"], pos_idx, axis=0)
-    x, new_g, new_t = transformer.run_stack_decode(
+    new = {}
+    if cfg.n_dense_layers:
+        x, new["lead"] = transformer.run_lead_decode(
+            cfg, base["lead"], adapter.get("lead"), cache["lead"], x,
+            positions, adapter_rows=adapter_rows)
+    x, new["groups"], new["tail"] = transformer.run_stack_decode(
         cfg, base["groups"], base["tail"], adapter["groups"], adapter["tail"],
         cache["groups"], cache["tail"], x, positions,
         adapter_rows=adapter_rows)
     with jax.named_scope("logits"):
         x = layers.norm(x, base["final_norm"], cfg.norm_type)
-        logits = layers.unembed(x, base["embed"], cfg.vocab_size)
+        logits = layers.unembed(x, head_table(cfg, base), cfg.vocab_size)
         if not pad_vocab and cfg.padded_vocab != cfg.vocab_size:
             logits = logits[..., :cfg.vocab_size]
-    return logits, {"groups": new_g, "tail": new_t}
+    return logits, new

@@ -10,20 +10,29 @@ TPU-native design notes (vs. the CUDA grouped-GEMM formulation):
   layer scan;
 - capacity overflow drops tokens (standard Switch behaviour); the router
   aux load-balance loss keeps the drop rate low.
+
+A layer may hold a share of the experts (``cfg.experts_held``, the chip's
+share under expert parallelism, model-configs §4): the router keeps all
+``n_experts`` outputs and its top-k, and the layer computes only experts
+[0, held) for the tokens routed to them.  What the absent experts would
+add is left out.  Shared experts (DeepSeek) are one dense MLP every token
+passes, added in full.  The experts are frozen: ``lora_mlp`` is refused.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.models import layers
 from repro.models.config import ModelConfig
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_held
     keys = jax.random.split(key, 4)
     s_in, s_out = 1.0 / jnp.sqrt(d), 1.0 / jnp.sqrt(f)
-    p = {"router": (jax.random.normal(keys[0], (d, e)) * s_in).astype(jnp.float32)}
+    p = {"router": (jax.random.normal(keys[0], (d, cfg.n_experts))
+                    * s_in).astype(jnp.float32)}
     if cfg.mlp_type == "swiglu":
         p["w_gate"] = (jax.random.normal(keys[1], (e, d, f)) * s_in).astype(cfg.dtype)
         p["w_up"] = (jax.random.normal(keys[2], (e, d, f)) * s_in).astype(cfg.dtype)
@@ -31,6 +40,9 @@ def init_moe(key, cfg: ModelConfig) -> dict:
     else:
         p["w_in"] = (jax.random.normal(keys[1], (e, d, f)) * s_in).astype(cfg.dtype)
         p["w_out"] = (jax.random.normal(keys[2], (e, f, d)) * s_out).astype(cfg.dtype)
+    if cfg.shared_d_ff:
+        p["shared"] = layers.init_mlp(jax.random.fold_in(key, 4), d,
+                                      cfg.shared_d_ff, cfg.mlp_type, cfg.dtype)
     return p
 
 
@@ -41,7 +53,9 @@ def capacity(cfg: ModelConfig, seq: int) -> int:
 
 
 def route(cfg: ModelConfig, router_w: jnp.ndarray, x: jnp.ndarray):
-    """x (B,S,D) -> (dispatch (B,S,E,C) bf16, combine (B,S,E,C) f32, aux loss)."""
+    """x (B,S,D) -> (dispatch (B,S,E',C) bf16, combine (B,S,E',C) f32, aux
+    loss), E' the held experts.  Softmax and greedy top-k run over all E;
+    the gates are renormalised over the top k only with ``norm_topk``."""
     b, s, _ = x.shape
     e, k, c = cfg.n_experts, cfg.top_k, capacity(cfg, s)
     logits = x.astype(jnp.float32) @ router_w                 # (B,S,E)
@@ -57,15 +71,17 @@ def route(cfg: ModelConfig, router_w: jnp.ndarray, x: jnp.ndarray):
         gates = gates + onehot * probs
         sel_onehot = sel_onehot + onehot
         masked = masked * (1.0 - onehot)
-    gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    if cfg.norm_topk:
+        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
 
     # position of each token inside its expert's buffer (per sequence group)
-    pos_in_expert = jnp.cumsum(sel_onehot, axis=1) * sel_onehot - 1.0  # (B,S,E)
+    held = sel_onehot[..., :cfg.n_held]                       # (B,S,E')
+    pos_in_expert = jnp.cumsum(held, axis=1) * held - 1.0
     keep = (pos_in_expert >= 0) & (pos_in_expert < c)
     pos_clamped = jnp.clip(pos_in_expert, 0, c - 1).astype(jnp.int32)
-    slot = jax.nn.one_hot(pos_clamped, c, dtype=jnp.float32)  # (B,S,E,C)
+    slot = jax.nn.one_hot(pos_clamped, c, dtype=jnp.float32)  # (B,S,E',C)
     dispatch = slot * keep[..., None]
-    combine = dispatch * gates[..., None]
+    combine = dispatch * gates[..., :cfg.n_held, None]
 
     # Switch load-balance auxiliary loss
     frac_tokens = jnp.mean(sel_onehot / max(k, 1), axis=1)    # (B,E)
@@ -78,16 +94,27 @@ MOE_GROUP = 1024          # tokens per dispatch group (capacity granularity)
 MOE_CHUNK_TOKENS = 16384  # max tokens in flight through the expert einsums
 
 
-def moe_mlp(cfg: ModelConfig, p: dict, x: jnp.ndarray, *,
-            adapters=None) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (out (B,S,D), aux_loss scalar).  Experts are FROZEN in CE-LoRA
-    fine-tuning (adapters attach to attention); ``adapters`` is accepted for
-    interface parity and applied to expert weights only when lora_mlp is set.
+def moe_mlp(cfg: ModelConfig, p: dict,
+            x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Returns (out (B,S,D), aux_loss scalar): the held experts' part plus
+    the shared experts, under the named scope ``moe``.  Experts are FROZEN
+    in CE-LoRA fine-tuning (adapters attach to attention), so a config
+    asking for MLP adapters (``lora_mlp``) is refused.
 
     Long sequences are split into MOE_GROUP-token dispatch groups so the
     one-hot dispatch/combine tensors stay O(group·E·C) per layer.
     """
-    del adapters  # MoE expert adaptation is out of scope (frozen experts)
+    if cfg.lora_mlp:
+        raise ValueError(f"{cfg.name}: lora_mlp asks for adapters on the "
+                         f"experts, which are frozen; adapt attention only")
+    with jax.named_scope("moe"):
+        out, aux = _routed(cfg, p, x)
+        if "shared" in p:
+            out = out + layers.mlp(x, p["shared"], cfg.mlp_type)
+    return out, aux
+
+
+def _routed(cfg: ModelConfig, p: dict, x: jnp.ndarray):
     b, s, d = x.shape
     group = min(MOE_GROUP, s)
     pad = (-s) % group

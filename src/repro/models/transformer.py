@@ -8,11 +8,12 @@ O(n_layers) — essential for compiling 64–80-layer models against a
 512-device mesh.
 
 Caches/recurrent state mirror the same (groups, tail) structure so decode
-scans params and cache together.
+scans params and cache together.  A config with ``n_dense_layers`` adds an
+unrolled leading section ("lead", a tuple of blocks with a dense MLP) to
+params, adapters and caches; other configs' trees have no such key.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Optional
 
 import jax
@@ -22,6 +23,12 @@ from repro.core import tri_lora
 from repro.models import attention, layers, moe, rglru, rwkv
 from repro.models.config import ModelConfig
 
+#: The cache entries of each attention kind that are decode rings: the layer
+#: scan carries them stacked and writes them in place.
+RING_KEYS = {"attn": ("k", "v"), "swa": ("k", "v"), "mla": ("latent",)}
+ATTN_KINDS = tuple(RING_KEYS)
+RING_NAMES = frozenset(n for names in RING_KEYS.values() for n in names)
+
 # ---------------------------------------------------------------------------
 # per-block init
 # ---------------------------------------------------------------------------
@@ -29,9 +36,15 @@ from repro.models.config import ModelConfig
 def _adapter_shapes(cfg: ModelConfig, kind: str, cross: bool) -> dict:
     d, hd, h, k = cfg.d_model, cfg.hd, cfg.n_heads, cfg.n_kv_heads
     f, rd = cfg.d_ff, cfg.rnn_d
-    if kind in ("attn", "swa"):
-        shapes = {"wq": (d, h * hd), "wk": (d, k * hd),
-                  "wv": (d, k * hd), "wo": (h * hd, d)}
+    if kind in ATTN_KINDS:
+        if kind == "mla":     # wkv_b stays frozen: decode absorbs it
+            qd = cfg.qk_nope_dim + cfg.qk_rope_dim
+            shapes = {"wq": (d, h * qd),
+                      "wkv_a": (d, cfg.kv_lora_rank + cfg.qk_rope_dim),
+                      "wo": (h * cfg.v_head_dim, d)}
+        else:
+            shapes = {"wq": (d, h * hd), "wk": (d, k * hd),
+                      "wv": (d, k * hd), "wo": (h * hd, d)}
         out = {"attn": {t: shapes[t] for t in cfg.lora_targets if t in shapes}}
         if cross:
             xs = {"wq": (d, h * hd), "wk": (d, h * hd),
@@ -65,19 +78,21 @@ def init_block_adapters(key, cfg: ModelConfig, kind: str, *,
 
 
 def init_block(key, cfg: ModelConfig, kind: str, *, cross: bool = False,
-               causal: bool = True) -> dict:
+               causal: bool = True, dense: bool = False) -> dict:
+    """``dense``: a leading layer, with a dense MLP even in an MoE model."""
     del causal
     d = cfg.d_model
     ks = jax.random.split(key, 6)
     nt = cfg.norm_type
-    if kind in ("attn", "swa"):
+    if kind in ATTN_KINDS:
+        init = attention.init_mla if kind == "mla" else attention.init_attn
         p = {"ln1": layers.init_norm(d, nt, cfg.dtype),
-             "attn": attention.init_attn(ks[0], cfg),
+             "attn": init(ks[0], cfg),
              "ln2": layers.init_norm(d, nt, cfg.dtype)}
         if cross:
             p["ln_x"] = layers.init_norm(d, nt, cfg.dtype)
             p["xattn"] = attention.init_attn(ks[1], cfg, cross=True)
-        if cfg.is_moe:
+        if cfg.is_moe and not dense:
             p["moe"] = moe.init_moe(ks[2], cfg)
         else:
             p["mlp"] = layers.init_mlp(ks[2], d, cfg.d_ff, cfg.mlp_type, cfg.dtype)
@@ -100,12 +115,29 @@ def init_block(key, cfg: ModelConfig, kind: str, *, cross: bool = False,
 # per-block apply (train)
 # ---------------------------------------------------------------------------
 
+def _ffn(cfg: ModelConfig, p: dict, ad: dict, h: jnp.ndarray,
+         adapter_rows=None):
+    """The block's MLP: its experts where it has them, else dense."""
+    if "moe" in p:
+        return moe.moe_mlp(cfg, p["moe"], h)
+    return layers.mlp(h, p["mlp"], cfg.mlp_type, adapters=ad.get("mlp"),
+                      lora_scaling=cfg.lora_alpha / cfg.lora_rank,
+                      adapter_rows=adapter_rows), jnp.zeros((), jnp.float32)
+
+
 def block_apply(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
                 x: jnp.ndarray, positions, *, enc_out=None, causal=True,
                 attn_impl=None, use_rwkv_kernel=False):
     ad = ad or {}
     nt = cfg.norm_type
     aux = jnp.zeros((), jnp.float32)
+    if kind == "mla":
+        with jax.named_scope("mla"):   # the MLA attention sub-block
+            h = layers.norm(x, p["ln1"], nt)
+            x = x + attention.mla_attention(cfg, p["attn"], h, positions,
+                                            ad.get("attn"), impl=attn_impl)
+        y, aux = _ffn(cfg, p, ad, layers.norm(x, p["ln2"], nt))
+        return x + y, aux
     if kind in ("attn", "swa"):
         window = cfg.window if kind == "swa" else 0
         h = layers.norm(x, p["ln1"], nt)
@@ -125,12 +157,7 @@ def block_apply(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
             h = layers.norm(x, p["ln_x"], nt)
             x = x + attention.cross_attention(cfg, p["xattn"], h, enc_out,
                                               ad.get("xattn"))
-        h = layers.norm(x, p["ln2"], nt)
-        if cfg.is_moe:
-            y, aux = moe.moe_mlp(cfg, p["moe"], h)
-        else:
-            y = layers.mlp(h, p["mlp"], cfg.mlp_type, adapters=ad.get("mlp"),
-                           lora_scaling=cfg.lora_alpha / cfg.lora_rank)
+        y, aux = _ffn(cfg, p, ad, layers.norm(x, p["ln2"], nt))
         return x + y, aux
     if kind == "rwkv6":
         h = layers.norm(x, p["ln1"], nt)
@@ -156,6 +183,8 @@ def block_apply(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
                      *, cross: bool = False) -> dict:
+    if kind == "mla":
+        return attention.init_latent_cache(cfg, batch, seq_len)
     if kind in ("attn", "swa"):
         window = cfg.window if kind == "swa" else 0
         c = attention.init_kv_cache(cfg, batch, seq_len, window=window)
@@ -177,9 +206,18 @@ def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
                  adapter_rows: Optional[jnp.ndarray] = None,
                  layer: Optional[jnp.ndarray] = None):
     """``layer``: the scan's group index when ``cache`` holds the stacked
-    K/V rings of an attention block (see :func:`run_stack_decode`)."""
+    rings of an attention block (see :func:`run_stack_decode`)."""
     ad = ad or {}
     nt = cfg.norm_type
+    if kind == "mla":
+        with jax.named_scope("mla"):   # the MLA attention sub-block
+            h = layers.norm(x, p["ln1"], nt)
+            y, new_cache = attention.decode_mla(
+                cfg, p["attn"], h, cache, positions, ad.get("attn"),
+                adapter_rows=adapter_rows, layer=layer)
+            x = x + y
+        y, _ = _ffn(cfg, p, ad, layers.norm(x, p["ln2"], nt), adapter_rows)
+        return x + y, new_cache
     if kind in ("attn", "swa"):
         window = cfg.window if kind == "swa" else 0
         h = layers.norm(x, p["ln1"], nt)
@@ -197,13 +235,7 @@ def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
             y = layers.dense(o.reshape(x.shape[0], 1, -1), p["xattn"]["wo"])
             x = x + y
             new_cache["xk"], new_cache["xv"] = cache["xk"], cache["xv"]
-        h = layers.norm(x, p["ln2"], nt)
-        if cfg.is_moe:
-            y, _ = moe.moe_mlp(cfg, p["moe"], h)
-        else:
-            y = layers.mlp(h, p["mlp"], cfg.mlp_type, adapters=ad.get("mlp"),
-                           lora_scaling=cfg.lora_alpha / cfg.lora_rank,
-                           adapter_rows=adapter_rows)
+        y, _ = _ffn(cfg, p, ad, layers.norm(x, p["ln2"], nt), adapter_rows)
         return x + y, new_cache
     if adapter_rows is not None:
         raise NotImplementedError(
@@ -250,6 +282,24 @@ def init_stack(key, cfg: ModelConfig, *, cross: bool = False) -> tuple:
     return (_stack(groups) if q else None), tail
 
 
+def init_lead(key, cfg: ModelConfig) -> tuple:
+    """The leading dense layers' params, one block each."""
+    keys = jax.random.split(key, max(cfg.n_dense_layers, 1))
+    return tuple(init_block(k, cfg, kind, dense=True)
+                 for k, kind in zip(keys, cfg.lead_kinds))
+
+
+def init_lead_adapters(key, cfg: ModelConfig) -> tuple:
+    keys = jax.random.split(key, max(cfg.n_dense_layers, 1))
+    return tuple(init_block_adapters(k, cfg, kind)
+                 for k, kind in zip(keys, cfg.lead_kinds))
+
+
+def init_lead_cache(cfg: ModelConfig, batch: int, seq_len: int) -> tuple:
+    return tuple(init_block_cache(cfg, kind, batch, seq_len)
+                 for kind in cfg.lead_kinds)
+
+
 def init_stack_adapters(key, cfg: ModelConfig, *, cross: bool = False) -> tuple:
     q, pattern, rem = cfg.stack_plan()
     n = len(pattern)
@@ -275,6 +325,30 @@ def init_stack_cache(cfg: ModelConfig, batch: int, seq_len: int, *,
 # ---------------------------------------------------------------------------
 # stack apply
 # ---------------------------------------------------------------------------
+
+def run_lead(cfg: ModelConfig, lead_p, lead_ad, x: jnp.ndarray, positions,
+             *, attn_impl=None):
+    """The leading dense layers, unrolled.  Returns (x, aux_sum)."""
+    aux = jnp.zeros((), jnp.float32)
+    for kind, p, ad in zip(cfg.lead_kinds, lead_p,
+                           lead_ad or (None,) * len(lead_p)):
+        x, a = block_apply(cfg, kind, p, ad, x, positions,
+                           attn_impl=attn_impl)
+        aux = aux + a
+    return x, aux
+
+
+def run_lead_decode(cfg: ModelConfig, lead_p, lead_ad, lead_cache,
+                    x: jnp.ndarray, positions, adapter_rows=None):
+    """One token through the leading layers; returns (x, new caches)."""
+    new = []
+    for kind, p, ad, c in zip(cfg.lead_kinds, lead_p,
+                              lead_ad or (None,) * len(lead_p), lead_cache):
+        x, c = block_decode(cfg, kind, p, ad, c, x, positions,
+                            adapter_rows=adapter_rows)
+        new.append(c)
+    return x, tuple(new)
+
 
 def run_stack(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
               x: jnp.ndarray, positions, *, enc_out=None, causal=True,
@@ -314,19 +388,20 @@ def run_stack_decode(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
                      adapter_rows=None):
     """One-token decode through the stack; returns (x, new caches).
 
-    The scan carries every attention block's stacked (q, B, K, W, hd) K/V
-    rings with the group index, so each layer scatters its new token into
-    the stacked ring in place and attention reads its layer straight out of
-    it; ``idx`` and every other state (recurrent states, cross caches) are
-    scanned per group as ``xs``/``ys``.  Tail blocks keep per-block rings.
+    The scan carries every attention block's stacked rings — (q, B, K, W,
+    hd) K/V rings, or (q, B, W, lanes) latent rings of MLA blocks — with
+    the group index, so each layer scatters its new token into the stacked
+    ring in place and attention reads its layer straight out of it; ``idx``
+    and every other state (recurrent states, cross caches) are scanned per
+    group as ``xs``/``ys``.  Tail blocks keep per-block rings.
 
     With ``adapter_rows`` (B,) the adapter trees carry a stacked bank axis
     — groups leaves (q, m, …), tail leaves (m, …), see
     ``adapter_bank.AdapterBank.decode_tree`` — and each batch row applies
     its own bank row (DESIGN.md §15)."""
     pattern = cfg.layer_pattern
-    carried = [str(i) for i, kind in enumerate(pattern)
-               if kind in ("attn", "swa")]
+    carried = {str(i): RING_KEYS[kind] for i, kind in enumerate(pattern)
+               if kind in RING_KEYS}
 
     def group_fn(carry, scanned):
         h, layer, rings = carry
@@ -338,7 +413,7 @@ def run_stack_decode(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
                 h, c = block_decode(cfg, kind, gp[key], gad[key],
                                     {**gc[key], **rings[key]}, h, positions,
                                     adapter_rows=adapter_rows, layer=layer)
-                rings[key] = {"k": c.pop("k"), "v": c.pop("v")}
+                rings[key] = {n: c.pop(n) for n in carried[key]}
             else:
                 h, c = block_decode(cfg, kind, gp[key], gad[key], gc[key], h,
                                     positions, adapter_rows=adapter_rows)
@@ -347,10 +422,10 @@ def run_stack_decode(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
 
     new_groups_cache = None
     if groups_p is not None:
-        rings = {i: {"k": groups_cache[i]["k"], "v": groups_cache[i]["v"]}
-                 for i in carried}
-        rest = {i: ({n: a for n, a in c.items() if n not in ("k", "v")}
-                    if i in rings else c) for i, c in groups_cache.items()}
+        rings = {i: {n: groups_cache[i][n] for n in names}
+                 for i, names in carried.items()}
+        rest = {i: ({n: a for n, a in c.items() if n not in carried[i]}
+                    if i in carried else c) for i, c in groups_cache.items()}
         (x, _, rings), new_rest = jax.lax.scan(
             group_fn, (x, jnp.zeros((), jnp.int32), rings),
             (groups_p, groups_ad, rest))

@@ -17,7 +17,8 @@ FAMS = ["qwen2.5-14b",        # dense GQA + bias
         "rwkv6-1.6b",         # SSM state
         "recurrentgemma-2b",  # hybrid RG-LRU + local attn
         "whisper-small",      # enc-dec cross attention
-        "grok-1-314b"]        # MoE (capacity_factor raised to avoid drops)
+        "grok-1-314b",        # MoE (capacity_factor raised to avoid drops)
+        "deepseek-v2-lite"]   # MLA latent ring, leading dense layer, MoE
 
 
 @pytest.mark.parametrize("arch", FAMS)

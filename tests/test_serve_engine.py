@@ -85,12 +85,14 @@ def test_engine_rejects_overlong_request(setup):
         eng.run(reqs)
 
 
-@pytest.mark.parametrize("arch", ["tiny", "h2o-danube-3-4b", "qwen2.5-14b"])
+@pytest.mark.parametrize("arch", ["tiny", "h2o-danube-3-4b", "qwen2.5-14b",
+                                  "deepseek-v2-lite"])
 def test_step_donates_its_cache(setup, arch):
     """Each step consumes the cache it is handed (its rings are written in
     place) and the cache it returns drives the next step: fed a 3-token
     prompt step by step it emits what a fresh engine emits.  Danube's ring
-    is cut to 2 slots, so the third token wraps it."""
+    is cut to 2 slots, so the third token wraps it; DeepSeek's latent rings
+    (the leading dense layer's and the scanned stack's) are donated too."""
     from repro.launch import serve
     cfg, base, bank = setup
     if arch != "tiny":
@@ -106,7 +108,7 @@ def test_step_donates_its_cache(setup, arch):
     rows = np.asarray([bank.lookup(r.user_id) for r in reqs], np.int32)
     for t in range(3):
         # the rings; the step installs ``pos`` in place of every ``idx``
-        old = [a for a in jax.tree.leaves(cache) if a.ndim >= 4]
+        old = [a for a in jax.tree.leaves(cache) if a.ndim >= 3]
         tok = np.asarray([[r.prompt[t]] for r in reqs], np.int32)
         nxt, cache = serve._serve_step(cfg, base, eng._bank_dec, cache, tok,
                                        np.full((2,), t, np.int32), rows)

@@ -117,3 +117,32 @@ def test_cache_specs_shard_the_ring_axis(tiny_cfg):
             assert specs["groups"][i][ring] == P(None, "data", None,
                                                  "model", None)
         assert specs["tail"][0][ring] == P("data", None, "model", None)
+
+
+def test_mla_and_expert_specs_have_their_leaves_ranks():
+    """DeepSeek-V2-Lite: the latent ring (B, ring, lanes) shards its ring
+    axis over `model` in the scanned stack and the leading layer alike;
+    every weight's spec has its leaf's rank; the untied output head shards
+    as the embedding table does; routed experts shard their expert axis
+    over `model`, the shared experts (a dense MLP) do not."""
+    mesh = MESHES["16x16"]
+    cfg = get_config("deepseek-v2-lite").with_overrides(experts_held=16)
+    cache = jax.eval_shape(lambda: model.init_decode_cache(cfg, 16, 64))
+    specs = shd.cache_specs(cache, mesh, cfg, ("data",))
+    assert specs["groups"]["0"]["latent"] == P(None, "data", "model", None)
+    assert specs["lead"][0]["latent"] == P("data", "model", None)
+    params = model.abstract_params(cfg)
+    pspecs = shd.param_specs(params, mesh, cfg)
+    flat = jax.tree_util.tree_flatten_with_path(
+        pspecs, is_leaf=lambda x: isinstance(x, P))[0]
+    for (path, spec), leaf in zip(flat, jax.tree.leaves(params)):
+        assert len(spec) == len(leaf.shape), (shd._path_names(path), spec)
+    assert pspecs["base"]["lm_head"] == pspecs["base"]["embed"] \
+        == P("model", "data")                   # the untied head, (V, D)
+    moe = pspecs["base"]["groups"]["0"]["moe"]
+    assert moe["w_gate"] == P(None, "model", "data", None)   # (L, E, d, f)
+    assert moe["shared"]["w_gate"] == P(None, "data", "model")
+    attn = pspecs["base"]["groups"]["0"]["attn"]
+    assert attn["wkv_a"] == P(None, "data", "model")
+    assert attn["wkv_b"] == P(None, "data", "model")
+    assert attn["wo"] == P(None, "model", "data")

@@ -7,6 +7,8 @@ forward with its logsumexp and its backward, the fused tri-LoRA matmul,
 and the federated trainer's own vmapped local fit at 24 of the model's 32
 layers, which must fit one chip's HBM with the frozen base as an argument.
 At Danube's attention shape: the serving step writes its KV ring in place.
+At DeepSeek-V2-Lite's widths and its cell's slots and ring: the MLA step
+writes its latent ring in place and never builds per-head keys or values.
 
 The topology is described inside a module fixture, never at import, and
 the persistent compilation cache is off around these compiles (an entry
@@ -191,3 +193,63 @@ def test_serve_step_updates_the_ring_in_place(one_chip):
     rings = 2 * math.prod(stacked) * 2                  # k and v, bf16
     assert mem.alias_size_in_bytes >= rings
     assert mem.temp_size_in_bytes < math.prod(stacked[1:]) * 2
+
+
+def _results(hlo: str):
+    """(name, dims, opcode) of every result outside fused computations."""
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo))
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            comp = head.group(1)
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                     line)
+        if m and comp not in fused:
+            yield (m.group(1), tuple(int(d) for d in m.group(2).split(",")
+                                     if d), m.group(3))
+
+
+def test_mla_serve_step_updates_the_latent_ring_in_place(one_chip):
+    """The serving step at DeepSeek-V2-Lite's widths (16 of 64 experts
+    held, the leading dense layer and 2 expert layers) with the cell's 64
+    slots and ring of 1408: each layer's new latent row goes into the
+    donated ring by one scatter, the ring is aliased input to output and
+    nothing else of its shape is materialised, no result has a per-head
+    key or value ring's shape (slots, heads and ring positions together),
+    and the step's scratch is under one layer's ring."""
+    from repro.core.adapter_bank import random_bank
+    from repro.launch import serve
+    from repro.models import model
+    from repro.models.config import get_config
+    cfg = get_config("deepseek-v2-lite").with_overrides(n_layers=3,
+                                                        experts_held=16)
+    slots, ring = 64, 1408
+    base = _on(one_chip, jax.eval_shape(
+        lambda: model.init_base(cfg, jax.random.key(0))))
+    bank = _on(one_chip, jax.eval_shape(
+        lambda: random_bank(cfg, 4, jax.random.key(1)).decode_tree()))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: model.init_decode_cache(cfg, slots, ring)))
+    ints = _on(one_chip, jax.ShapeDtypeStruct((slots,), jnp.int32))
+    toks = _on(one_chip, jax.ShapeDtypeStruct((slots, 1), jnp.int32))
+    compiled = serve._serve_step.lower(
+        cfg, base, bank, cache, toks, ints, ints).compile()
+
+    stacked = cache["groups"]["0"]["latent"].shape
+    assert stacked == (2, slots, ring, 640)        # 512 + 64 in 640 lanes
+    assert cache["lead"][0]["latent"].shape == stacked[1:]
+    hlo = compiled.as_text()
+    found, scatters = _ring_traffic(
+        hlo, {stacked, stacked[1:], (1,) + stacked[1:]})
+    assert not found, found
+    assert scatters >= 2                           # lead layer and the scan
+    # the scores and probabilities are (slots, heads, ring); a per-head key
+    # or value ring would add a feature axis
+    per_head = [(n, d, op) for n, d, op in _results(hlo)
+                if len(d) >= 4 and {slots, cfg.n_heads, ring} <= set(d)]
+    assert not per_head, per_head
+    mem = compiled.memory_analysis()
+    one_layer = math.prod(stacked[1:]) * 2         # bf16
+    assert mem.alias_size_in_bytes >= 3 * one_layer
+    assert mem.temp_size_in_bytes < one_layer
