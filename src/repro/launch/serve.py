@@ -13,9 +13,12 @@ Two inference modes for paper eqn (10)'s per-client adapters:
   from DISTINCT users is decoded in one continuously-batched loop, each
   batch slot applying its own tri-LoRA row from an
   :class:`~repro.core.adapter_bank.AdapterBank` (grouped heterogeneous
-  decode).  Finished requests free their slot for the next arrival; slot
-  reuse is safe because a reused slot restarts at position 0 and the ring
-  validity mask (``slot <= idx``) hides every stale KV entry.
+  decode).  An admitted request's prompt goes into its slot's ring in
+  chunks of :data:`PREFILL_CHUNK` tokens by the prefill program, and its
+  decode starts at the prompt's last token.  Finished requests free their
+  slot for the next arrival; slot reuse is safe because a reused slot
+  restarts at position 0 and the ring validity mask (``slot <= idx``)
+  hides every stale KV entry.
 * :func:`serve_naive` — the baseline the benchmark beats: per user, merge
   that user's adapter into the base weights (eqn. 10) and decode batch-1,
   sequentially.
@@ -23,12 +26,14 @@ Two inference modes for paper eqn (10)'s per-client adapters:
 ``ServeEngine.run`` writes host spans into the profiler's trace whenever
 one is being recorded (``jax.profiler.trace(dir)`` around the call):
 ``serve.run``, ``serve.ring_init``, and per loop iteration a
-``serve.step`` holding ``serve.admit``, ``serve.dispatch``, ``serve.sync``
-and ``serve.bookkeep``.  ``serve.run`` carries ``ServeEngine.stats``, the
-slot count and the per-layer KV ring shape as metadata.  The decode step's
-operations carry the named scopes ``kv_ring``, ``tri_lora``, ``attention``
-and ``logits`` in their ``op_name`` metadata; an MLA model's attention
-sub-blocks run under ``mla`` and its expert layers under ``moe``.
+``serve.step`` holding ``serve.admit``, ``serve.prefill``,
+``serve.dispatch``, ``serve.sync`` and ``serve.bookkeep``.  ``serve.run``
+carries ``ServeEngine.stats``, the slot count and the per-layer KV ring
+shape as metadata.  The decode step's operations carry the named scopes
+``kv_ring``, ``tri_lora``, ``attention`` and ``logits`` in their
+``op_name`` metadata; an MLA model's attention sub-blocks run under
+``mla`` and its expert layers under ``moe``.  The prefill program runs
+under ``prefill``.
 """
 from __future__ import annotations
 
@@ -129,6 +134,38 @@ def _with_positions(cache: dict, pos: jnp.ndarray) -> dict:
     return jax.tree.unflatten(treedef, leaves)
 
 
+#: Prompt tokens a prefill call takes.  A call reads every weight once, as
+#: a decode step does, and 128 tokens is about the largest chunk whose
+#: FLOPs stay under that read on a v5e: at Danube's 3.96 B parameters
+#: 2 x 3.96e9 x 128 is about 1.0 TFLOP, 5 ms at 197 TFLOP/s, against 9.4 ms
+#: for its 7.7 GB of weights at 819 GB/s.  So a call costs about one weight
+#: read, where feeding the same tokens one a decode step cost 128 slot-steps.
+PREFILL_CHUNK = 128
+
+
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=3)
+def _prefill(cfg, base, bank_dec, cache, tok, start, n, slot, row):
+    """One slot's prompt chunk into the ring, in place: ``tok`` (1, C)
+    holds ``n`` real tokens for positions ``start + [0, n)`` of ring row
+    ``slot``, each through bank row ``row``; padding writes nothing.  The
+    cache is donated; nothing is read back (no logits)."""
+    with jax.named_scope("prefill"):
+        i = jnp.arange(tok.shape[1], dtype=jnp.int32)
+        pos = jnp.where(i < n, start + i, -1)[None]
+        positions = (jnp.broadcast_to(pos[..., None], pos.shape + (3,))
+                     if cfg.pos_type == "mrope" else pos)
+        # the call's one bank row, cut out before the layer scan: handed the
+        # whole bank, XLA re-lays all of it every call
+        one = jax.tree_util.tree_map_with_path(
+            lambda path, a: jax.lax.dynamic_slice_in_dim(
+                a, row, 1, axis=1 if path[0].key == "groups" else 0),
+            bank_dec)
+        _, cache = model.decode_step(
+            cfg, base, one, cache, {"token": tok, "positions": positions},
+            adapter_rows=jnp.zeros((1,), jnp.int32), ring_row=slot)
+    return cache
+
+
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=3)
 def _serve_step(cfg, base, bank_dec, cache, tok, pos, rows):
     """One continuous-batching decode step; greedy next token per slot.
@@ -148,25 +185,38 @@ def _serve_step(cfg, base, bank_dec, cache, tok, pos, rows):
 class ServeStats:
     """What one ``ServeEngine.run`` did, counted in its slot loop.  Each
     slot of each step is exactly one of prefill (fed a prompt token whose
-    output is discarded), emit (its output token was emitted) or empty."""
+    output is discarded), emit (its output token was emitted) or empty;
+    prompts go through prefill calls, so no slot-step is prefill.
+    ``prefill_calls`` counts the prefill program's calls and
+    ``prefill_tokens`` the prompt tokens they wrote, padding excluded."""
     steps: int = 0
     slot_steps_prefill: int = 0
     slot_steps_emit: int = 0
     slot_steps_empty: int = 0
     admitted: int = 0
     finished: int = 0
+    prefill_calls: int = 0
+    prefill_tokens: int = 0
 
 
-def _ring_shapes(cache: dict) -> str:
-    """The per-layer ring shapes of a decode cache, as ``BxKxWxhd`` for K/V
-    rings and ``BxWxlanes`` for MLA's latent ring (several joined by
-    ``;``): scanned groups drop their leading layer axis."""
+def _ring_shapes(cache: dict) -> list:
+    """The per-layer ring shapes of a decode cache, ``(B, K, W, hd)`` for
+    K/V rings and ``(B, W, lanes)`` for MLA's latent ring: scanned groups
+    drop their leading layer axis."""
     out = set()
     for path, leaf in jax.tree.flatten_with_path(cache)[0]:
         if getattr(path[-1], "key", None) in transformer.RING_NAMES:
-            shape = leaf.shape[1:] if path[0].key == "groups" else leaf.shape
-            out.add("x".join(map(str, shape)))
-    return ";".join(sorted(out))
+            out.add(leaf.shape[1:] if path[0].key == "groups"
+                    else leaf.shape)
+    return sorted(out)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _init_cache(cfg, slots: int, max_len: int) -> dict:
+    """The engine's decode cache, every ``idx`` already per slot (all -1,
+    no slot live): the shapes every step and prefill call is handed."""
+    return _with_positions(model.init_decode_cache(cfg, slots, max_len),
+                           jnp.full((slots,), -1, jnp.int32))
 
 
 class ServeEngine:
@@ -194,8 +244,10 @@ class ServeEngine:
         self.cfg, self.base, self.bank = cfg, base, bank
         self.slots, self.max_len = slots, max_len
         self._bank_dec = bank.decode_tree()
-        self._ring = _ring_shapes(jax.eval_shape(
+        rings = _ring_shapes(jax.eval_shape(
             lambda: model.init_decode_cache(cfg, slots, max_len)))
+        self._ring = ";".join("x".join(map(str, r)) for r in rings)
+        self._ring_len = min(r[-2] for r in rings)
         self.stats = ServeStats()
 
     def run(self, requests: Sequence[Request],
@@ -206,6 +258,9 @@ class ServeEngine:
             if need > self.max_len:
                 raise ValueError(f"request {r.rid} needs {need} positions "
                                  f"> max_len={self.max_len}")
+            if len(r.prompt) - 1 > self._ring_len:   # prefill never wraps
+                raise ValueError(f"request {r.rid}'s prompt would wrap a "
+                                 f"ring of {self._ring_len} positions")
         st = self.stats = ServeStats()
         with jax.profiler.TraceAnnotation("serve.run") as span:
             done = self._drain(list(requests), st, progress)
@@ -217,8 +272,7 @@ class ServeEngine:
                progress: bool) -> Dict[int, np.ndarray]:
         n_req = len(queue)
         with jax.profiler.TraceAnnotation("serve.ring_init"):
-            cache = model.init_decode_cache(self.cfg, self.slots,
-                                            self.max_len)
+            cache = _init_cache(self.cfg, self.slots, self.max_len)
         active: List[Optional[Request]] = [None] * self.slots
         emitted: Dict[int, List[int]] = {}
         pos = np.full((self.slots,), -1, np.int32)
@@ -230,15 +284,23 @@ class ServeEngine:
             with jax.profiler.StepTraceAnnotation("serve.step",
                                                   step_num=st.steps):
                 with jax.profiler.TraceAnnotation("serve.admit"):
+                    new = []
                     for s in range(self.slots):   # arrivals into free slots
                         if active[s] is None and queue:
                             r = queue.pop(0)
                             active[s] = r
                             emitted[r.rid] = list(r.prompt)
-                            pos[s] = 0        # slot REUSE: ring restarts; the
-                            rows[s] = self.bank.lookup(r.user_id)  # validity
-                            tok[s] = int(r.prompt[0])  # mask (slot <= idx)
-                            st.admitted += 1           # hides stale KV
+                            rows[s] = self.bank.lookup(r.user_id)
+                            # slot REUSE: the ring restarts at 0; the mask
+                            # (slot <= position) hides stale KV
+                            pos[s] = len(r.prompt) - 1
+                            tok[s] = int(r.prompt[-1])
+                            st.admitted += 1
+                            new.append(s)
+                with jax.profiler.TraceAnnotation("serve.prefill"):
+                    for s in new:
+                        cache = self._write_prompt(
+                            cache, active[s].prompt[:-1], s, rows[s], st)
                 with jax.profiler.TraceAnnotation("serve.dispatch"):
                     nxt, cache = _serve_step(
                         self.cfg, self.base, self._bank_dec, cache,
@@ -252,6 +314,23 @@ class ServeEngine:
             st.steps += 1
         return done
 
+    def _write_prompt(self, cache, prompt: np.ndarray, slot: int, row: int,
+                      st: ServeStats):
+        """Prefill calls that write ``prompt`` into ring row ``slot`` from
+        position 0, :data:`PREFILL_CHUNK` tokens a call; dispatched, never
+        waited for."""
+        c = PREFILL_CHUNK
+        for start in range(0, len(prompt), c):
+            part = prompt[start:start + c]
+            tok = np.zeros((1, c), np.int32)
+            tok[0, :len(part)] = part
+            cache = _prefill(self.cfg, self.base, self._bank_dec, cache, tok,
+                             np.int32(start), np.int32(len(part)),
+                             np.int32(slot), np.int32(row))
+            st.prefill_calls += 1
+            st.prefill_tokens += len(part)
+        return cache
+
     def _bookkeep(self, nxt, active, emitted, pos, rows, tok, done,
                   st: ServeStats, progress: bool, n_req: int) -> None:
         for s in range(self.slots):
@@ -259,17 +338,11 @@ class ServeEngine:
             if r is None:
                 st.slot_steps_empty += 1
                 continue
-            t = int(pos[s])
-            total = len(r.prompt) + r.gen
-            if t < len(r.prompt) - 1:     # still feeding the prompt
-                tok[s] = int(r.prompt[t + 1])
-                st.slot_steps_prefill += 1
-            else:                         # greedy continuation
-                emitted[r.rid].append(int(nxt[s]))
-                tok[s] = int(nxt[s])
-                st.slot_steps_emit += 1
+            emitted[r.rid].append(int(nxt[s]))     # greedy continuation
+            tok[s] = int(nxt[s])
+            st.slot_steps_emit += 1
             pos[s] += 1
-            if len(emitted[r.rid]) >= total:
+            if len(emitted[r.rid]) >= len(r.prompt) + r.gen:
                 done[r.rid] = np.asarray(emitted.pop(r.rid), np.int32)
                 st.finished += 1
                 if progress:

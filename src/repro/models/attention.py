@@ -378,7 +378,7 @@ def cross_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# decode (one token, ring-buffered KV cache)
+# decode (one token, or a prompt chunk, against a ring-buffered KV cache)
 # ---------------------------------------------------------------------------
 
 #: The K/V ring stores each head's vector padded with zeros to a multiple
@@ -399,19 +399,21 @@ def _lane_pad(x: jnp.ndarray, width: int) -> jnp.ndarray:
 
 def ring_sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               valid: jnp.ndarray) -> jnp.ndarray:
-    """One query token against head-major rings: q (B,1,H,hd), k/v
-    (B,K,W,ring_head_dim(hd)), valid (B,W).  :func:`sdpa`'s arithmetic:
-    f32 logits (the zero lanes add exact zeros) and softmax, the output
-    cast to q's dtype."""
-    b, _, h, hd = q.shape
+    """Queries against head-major rings: q (B,S,H,hd), k/v
+    (B,K,W,ring_head_dim(hd)), valid (B,W) for one query token, (B,S,W)
+    for S.  :func:`sdpa`'s arithmetic: f32 logits (the zero lanes add
+    exact zeros) and softmax, the output cast to q's dtype."""
+    b, s, h, hd = q.shape
     kh = k.shape[1]
-    qg = _lane_pad(q.reshape(b, kh, h // kh, hd), k.shape[-1])
-    logits = jnp.einsum("bkgd,bksd->bkgs", qg.astype(jnp.float32),
+    qa, lead = ("q", (b, s)) if s > 1 else ("", (b,))  # one: no query axis
+    qg = _lane_pad(q.reshape(lead + (kh, h // kh, hd)), k.shape[-1])
+    logits = jnp.einsum(f"b{qa}kgd,bksd->b{qa}kgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / jnp.sqrt(hd).astype(jnp.float32)
-    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
+    logits = jnp.where(valid[..., None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bksd->bkgd", probs, v.astype(jnp.float32))
-    return out[..., :hd].reshape(b, 1, h, hd).astype(q.dtype)
+    out = jnp.einsum(f"b{qa}kgs,bksd->b{qa}kgd", probs,
+                     v.astype(jnp.float32))
+    return out[..., :hd].reshape(b, s, h, hd).astype(q.dtype)
 
 
 def _ring_position(idx, ring: int, b: int):
@@ -433,15 +435,46 @@ def _ring_position(idx, ring: int, b: int):
             jnp.where(active, idx + 1, idx), valid)
 
 
+def _chunk_position(positions, ring: int, b_ring: int, ring_row):
+    """Where a chunk of S tokens per row writes and what it may read.
+    ``positions`` (B, S) are the tokens' absolute positions, -1 for
+    padding; rows of the chunk are ring rows ``ring_row + [0, B)``.
+    Returns the ring row of each token (``b_ring``, out of bounds, for
+    padding, so its write is dropped), its ring slot, and the (B, S, ring)
+    causal mask: slots [0, position].  A chunk never wraps the ring."""
+    b = positions.shape[0]
+    rows = ring_row + jnp.arange(b, dtype=jnp.int32)[:, None]
+    real = positions >= 0
+    return (jnp.where(real, rows, b_ring), jnp.where(real, positions, 0),
+            jnp.arange(ring)[None, None, :] <= positions[..., None])
+
+
+def _ring_rows(ring: jnp.ndarray, layer, ring_row, b: int) -> jnp.ndarray:
+    """The ring rows a call reads: layer ``layer`` of a stacked ring (or
+    the ring itself), all rows for a decode step, rows ``ring_row + [0,
+    b)`` for a chunk."""
+    ring = ring if layer is None else ring[layer]
+    if ring_row is None:
+        return ring
+    return jax.lax.dynamic_slice_in_dim(ring, ring_row, b, axis=0)
+
+
 def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                           cache: dict, positions, adapters=None,
                           *, window: int = 0,
                           adapter_rows: Optional[jnp.ndarray] = None,
-                          layer: Optional[jnp.ndarray] = None):
+                          layer: Optional[jnp.ndarray] = None,
+                          ring_row: Optional[jnp.ndarray] = None):
     """x: (B, 1, D).  cache: {'k','v': (B, K, W, ring_head_dim(hd)) head-
     major rings, 'idx': int32 scalar — or (B,) for RAGGED per-row positions
     (DESIGN.md §15): each sequence advances independently, and rows at idx
     -1 are masked batch slots that write nothing and attend to nothing}.
+
+    x: (B, S, D) with S > 1 is a prompt chunk (DESIGN.md §18): token i of
+    row b goes to position ``positions[b, i]`` (-1 for padding, which
+    writes nothing) of ring row ``ring_row + b`` (an int32 scalar),
+    attends to that row's slots [0, its position] and must not wrap the
+    ring; ``idx`` is passed through unchanged.
 
     ``W`` is the ring size (== window for SWA blocks, == max_len otherwise).
     Keys are stored post-rope; with rotary embeddings relative offsets are
@@ -460,31 +493,36 @@ def decode_self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     q = _rope(cfg, q, positions)
     k_new = _rope(cfg, k_new, positions)
 
-    b = x.shape[0]
-    ring = cache["k"].shape[-2]
+    b, s = x.shape[:2]
+    tok = (lambda t: t[:, 0]) if s == 1 else (lambda t: t)
+    kh, ring, lanes = cache["k"].shape[-3:]
     at = () if layer is None else (layer,)
     with jax.named_scope("kv_ring"):      # ring write + validity mask
-        slot, wb, new_idx, valid = _ring_position(cache["idx"], ring, b)
-        # one (K, hd) row per batch row, at [layer, row, :, slot]; with the
-        # heads indexed too each write is one contiguous hd row, so XLA
-        # keeps the ring row-major, the layout the attention dots read
-        kh = cache["k"].shape[-3]
-        where = at + (wb[:, None], jnp.arange(kh)[None, :],
-                      jnp.reshape(slot, (-1, 1)))
-        lanes = cache["k"].shape[-1]
+        if s == 1:
+            slot, wb, new_idx, valid = _ring_position(cache["idx"], ring, b)
+            # one (K, hd) row per batch row, at [layer, row, :, slot]; with
+            # the heads indexed too each write is one contiguous hd row, so
+            # XLA keeps the ring row-major, the layout the attention dots
+            # read
+            where = at + (wb[:, None], jnp.arange(kh)[None, :],
+                          jnp.reshape(slot, (-1, 1)))
+        else:                             # (B, S, K) such rows
+            wb, slot, valid = _chunk_position(positions, ring,
+                                              cache["k"].shape[-4], ring_row)
+            where = at + (wb[..., None], jnp.arange(kh), slot[..., None])
+            new_idx = cache["idx"]
         k_all = cache["k"].at[where].set(
-            _lane_pad(k_new[:, 0], lanes).astype(cache["k"].dtype),
+            _lane_pad(tok(k_new), lanes).astype(cache["k"].dtype),
             mode="drop")
         v_all = cache["v"].at[where].set(
-            _lane_pad(v_new[:, 0], lanes).astype(cache["v"].dtype),
+            _lane_pad(tok(v_new), lanes).astype(cache["v"].dtype),
             mode="drop")
     with jax.named_scope("attention"):
-        k, v = (k_all, v_all) if layer is None else (k_all[layer],
-                                                     v_all[layer])
-        out = ring_sdpa(q, k, v, valid)
+        out = ring_sdpa(q, _ring_rows(k_all, layer, ring_row, b),
+                        _ring_rows(v_all, layer, ring_row, b), valid)
     sc = cfg.lora_alpha / cfg.lora_rank
     ad = adapters or {}
-    y = layers.dense(out.reshape(b, 1, -1), p["wo"], adapter=ad.get("wo"),
+    y = layers.dense(out.reshape(b, s, -1), p["wo"], adapter=ad.get("wo"),
                      lora_scaling=sc, adapter_rows=adapter_rows)
     return y, {"k": k_all, "v": v_all, "idx": new_idx}
 
@@ -607,38 +645,48 @@ def init_latent_cache(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
 def decode_mla(cfg: ModelConfig, p: dict, x: jnp.ndarray, cache: dict,
                positions, adapters=None, *,
                adapter_rows: Optional[jnp.ndarray] = None,
-               layer: Optional[jnp.ndarray] = None):
+               layer: Optional[jnp.ndarray] = None,
+               ring_row: Optional[jnp.ndarray] = None):
     """One token of MLA against the latent ring, Wkv_b absorbed:
     q_lat,h = W_UK,h q_nope,h scores against c and q_pe against k_pe, and
     o_h = W_UV,h^T (sum_s p c).  cache: {'latent': (B, W, lanes), or a
     layer scan's stacked (L, B, W, lanes) with ``layer``; 'idx'} — the
     ring's write, mask and ragged ``idx`` follow
-    :func:`decode_self_attention`."""
-    b = x.shape[0]
+    :func:`decode_self_attention`, and so does a chunk of S > 1 tokens a
+    row (``ring_row``), by the same absorbed formula."""
+    b, s = x.shape[:2]
     h, nope, vd, r = (cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim,
                       cfg.kv_lora_rank)
     q_nope, q_pe, c, k_pe = _mla_project(cfg, p, x, positions, adapters,
                                          adapter_rows)
+    qa = "q" if s > 1 else ""                  # one token: no query axis
+    tok = (lambda t: t[:, 0]) if s == 1 else (lambda t: t)
     ring, lanes = cache["latent"].shape[-2:]
     at = () if layer is None else (layer,)
     with jax.named_scope("kv_ring"):
-        slot, wb, new_idx, valid = _ring_position(cache["idx"], ring, b)
-        row = _lane_pad(jnp.concatenate([c[:, 0], k_pe[:, 0]], -1), lanes)
+        if s == 1:
+            slot, wb, new_idx, valid = _ring_position(cache["idx"], ring, b)
+        else:
+            wb, slot, valid = _chunk_position(
+                positions, ring, cache["latent"].shape[-3], ring_row)
+            new_idx = cache["idx"]
+        row = _lane_pad(jnp.concatenate([tok(c), tok(k_pe)], -1), lanes)
         lat = cache["latent"].at[at + (wb, slot)].set(
             row.astype(cache["latent"].dtype), mode="drop")
     with jax.named_scope("attention"):
-        kv = (lat if layer is None else lat[layer]).astype(jnp.float32)
+        kv = _ring_rows(lat, layer, ring_row, b).astype(jnp.float32)
         w = p["wkv_b"].reshape(r, h, nope + vd).astype(jnp.float32)
-        q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(jnp.float32),
-                           w[..., :nope])
+        q_lat = jnp.einsum(f"b{qa}hn,chn->b{qa}hc",
+                           tok(q_nope).astype(jnp.float32), w[..., :nope])
         qf = _lane_pad(jnp.concatenate(
-            [q_lat, q_pe[:, 0].astype(jnp.float32)], -1), lanes)
-        logits = jnp.einsum("bhd,bsd->bhs", qf, kv) * mla_softmax_scale(cfg)
+            [q_lat, tok(q_pe).astype(jnp.float32)], -1), lanes)
+        logits = jnp.einsum(f"b{qa}hd,bsd->b{qa}hs", qf,
+                            kv) * mla_softmax_scale(cfg)
         probs = jax.nn.softmax(
-            jnp.where(valid[:, None, :], logits, NEG_INF), axis=-1)
-        o_lat = jnp.einsum("bhs,bsd->bhd", probs, kv)[..., :r]
-        o = jnp.einsum("bhc,chv->bhv", o_lat, w[..., nope:])
-    y = layers.dense(o.reshape(b, 1, h * vd).astype(x.dtype), p["wo"],
+            jnp.where(valid[..., None, :], logits, NEG_INF), axis=-1)
+        o_lat = jnp.einsum(f"b{qa}hs,bsd->b{qa}hd", probs, kv)[..., :r]
+        o = jnp.einsum(f"b{qa}hc,chv->b{qa}hv", o_lat, w[..., nope:])
+    y = layers.dense(o.reshape(b, s, h * vd).astype(x.dtype), p["wo"],
                      adapter=(adapters or {}).get("wo"),
                      lora_scaling=cfg.lora_alpha / cfg.lora_rank,
                      adapter_rows=adapter_rows)

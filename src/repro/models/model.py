@@ -8,8 +8,9 @@ train:   {'tokens': (B,S) i32, 'labels': (B,S) i32,
           'positions': (B,S) i32  or (B,S,3) for M-RoPE,
           ['vision': (B,P,D)]  (vlm stub embeds, prepended — early fusion),
           ['frames': (B,F,D)]  (audio stub embeds, encoder input)}
-decode:  {'token': (B,1) i32, 'positions': (B,1) or (B,1,3) i32}
-         + cache pytree from :func:`init_decode_cache`.
+decode:  {'token': (B,S) i32, 'positions': (B,S) or (B,S,3) i32}
+         + cache pytree from :func:`init_decode_cache`; S = 1 is a decode
+         step, S > 1 a prompt chunk written into the rings.
 """
 from __future__ import annotations
 
@@ -233,8 +234,11 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
 
 def decode_step(cfg: ModelConfig, base: dict, adapter: dict, cache: dict,
                 batch: dict, pad_vocab: bool = False,
-                adapter_rows=None) -> tuple[jnp.ndarray, dict]:
-    """One new token against the cache.  Returns (logits (B,1,V), new cache).
+                adapter_rows=None, ring_row=None) -> tuple[jnp.ndarray, dict]:
+    """S new tokens a row against the cache.  Returns (logits (B,S,V), new
+    cache).  S = 1 is a decode step; S > 1 a prompt chunk whose tokens go
+    into ring rows ``ring_row + [0, B)`` at ``positions`` (-1: padding,
+    written nowhere) and attend causally (DESIGN.md §18).
     ``pad_vocab`` keeps the padded (shardable) vocab dim — distributed path.
     ``adapter_rows`` (B,) int32 switches ``adapter`` to a stacked bank
     (``AdapterBank.decode_tree()``): each batch row applies its own adapter
@@ -250,11 +254,11 @@ def decode_step(cfg: ModelConfig, base: dict, adapter: dict, cache: dict,
     if cfg.n_dense_layers:
         x, new["lead"] = transformer.run_lead_decode(
             cfg, base["lead"], adapter.get("lead"), cache["lead"], x,
-            positions, adapter_rows=adapter_rows)
+            positions, adapter_rows=adapter_rows, ring_row=ring_row)
     x, new["groups"], new["tail"] = transformer.run_stack_decode(
         cfg, base["groups"], base["tail"], adapter["groups"], adapter["tail"],
         cache["groups"], cache["tail"], x, positions,
-        adapter_rows=adapter_rows)
+        adapter_rows=adapter_rows, ring_row=ring_row)
     with jax.named_scope("logits"):
         x = layers.norm(x, base["final_norm"], cfg.norm_type)
         logits = layers.unembed(x, head_table(cfg, base), cfg.vocab_size)

@@ -178,7 +178,7 @@ def block_apply(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
 
 
 # ---------------------------------------------------------------------------
-# per-block decode (one token, carries cache/state)
+# per-block decode (one token or a prompt chunk, carries cache/state)
 # ---------------------------------------------------------------------------
 
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
@@ -201,12 +201,27 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
     raise ValueError(kind)
 
 
+def _ffn_decode(cfg: ModelConfig, p: dict, ad: dict, h: jnp.ndarray,
+                adapter_rows=None):
+    """:func:`_ffn` for x (B, S, D) against a cache: an expert layer routes
+    each of a chunk's tokens as its own dispatch group, as it routes a
+    decode step's, so no expert's capacity drops a token."""
+    b, s, d = h.shape
+    if "moe" in p and s > 1:
+        y, aux = _ffn(cfg, p, ad, h.reshape(b * s, 1, d))
+        return y.reshape(b, s, d), aux
+    return _ffn(cfg, p, ad, h, adapter_rows)
+
+
 def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
                  cache: dict, x: jnp.ndarray, positions,
                  adapter_rows: Optional[jnp.ndarray] = None,
-                 layer: Optional[jnp.ndarray] = None):
+                 layer: Optional[jnp.ndarray] = None,
+                 ring_row: Optional[jnp.ndarray] = None):
     """``layer``: the scan's group index when ``cache`` holds the stacked
-    rings of an attention block (see :func:`run_stack_decode`)."""
+    rings of an attention block (see :func:`run_stack_decode`).  x (B, S,
+    D) with S > 1 is a prompt chunk written to ring rows ``ring_row +
+    [0, B)`` (attention blocks only; :func:`attention.decode_self_attention`)."""
     ad = ad or {}
     nt = cfg.norm_type
     if kind == "mla":
@@ -214,9 +229,10 @@ def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
             h = layers.norm(x, p["ln1"], nt)
             y, new_cache = attention.decode_mla(
                 cfg, p["attn"], h, cache, positions, ad.get("attn"),
-                adapter_rows=adapter_rows, layer=layer)
+                adapter_rows=adapter_rows, layer=layer, ring_row=ring_row)
             x = x + y
-        y, _ = _ffn(cfg, p, ad, layers.norm(x, p["ln2"], nt), adapter_rows)
+        y, _ = _ffn_decode(cfg, p, ad, layers.norm(x, p["ln2"], nt),
+                           adapter_rows)
         return x + y, new_cache
     if kind in ("attn", "swa"):
         window = cfg.window if kind == "swa" else 0
@@ -224,7 +240,7 @@ def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
         y, kv = attention.decode_self_attention(
             cfg, p["attn"], h, {k: cache[k] for k in ("k", "v", "idx")},
             positions, ad.get("attn"), window=window,
-            adapter_rows=adapter_rows, layer=layer)
+            adapter_rows=adapter_rows, layer=layer, ring_row=ring_row)
         x = x + y
         new_cache = dict(kv)
         if "xattn" in p:
@@ -235,7 +251,8 @@ def block_decode(cfg: ModelConfig, kind: str, p: dict, ad: Optional[dict],
             y = layers.dense(o.reshape(x.shape[0], 1, -1), p["xattn"]["wo"])
             x = x + y
             new_cache["xk"], new_cache["xv"] = cache["xk"], cache["xv"]
-        y, _ = _ffn(cfg, p, ad, layers.norm(x, p["ln2"], nt), adapter_rows)
+        y, _ = _ffn_decode(cfg, p, ad, layers.norm(x, p["ln2"], nt),
+                           adapter_rows)
         return x + y, new_cache
     if adapter_rows is not None:
         raise NotImplementedError(
@@ -339,13 +356,15 @@ def run_lead(cfg: ModelConfig, lead_p, lead_ad, x: jnp.ndarray, positions,
 
 
 def run_lead_decode(cfg: ModelConfig, lead_p, lead_ad, lead_cache,
-                    x: jnp.ndarray, positions, adapter_rows=None):
-    """One token through the leading layers; returns (x, new caches)."""
+                    x: jnp.ndarray, positions, adapter_rows=None,
+                    ring_row=None):
+    """A token (or a chunk) through the leading layers; returns (x, new
+    caches)."""
     new = []
     for kind, p, ad, c in zip(cfg.lead_kinds, lead_p,
                               lead_ad or (None,) * len(lead_p), lead_cache):
         x, c = block_decode(cfg, kind, p, ad, c, x, positions,
-                            adapter_rows=adapter_rows)
+                            adapter_rows=adapter_rows, ring_row=ring_row)
         new.append(c)
     return x, tuple(new)
 
@@ -385,8 +404,10 @@ def run_stack(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
 
 def run_stack_decode(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
                      groups_cache, tail_cache, x: jnp.ndarray, positions,
-                     adapter_rows=None):
-    """One-token decode through the stack; returns (x, new caches).
+                     adapter_rows=None, ring_row=None):
+    """One-token decode through the stack; returns (x, new caches).  x (B,
+    S, D) with S > 1 is a prompt chunk, written to ring rows ``ring_row +
+    [0, B)`` (:func:`attention.decode_self_attention`).
 
     The scan carries every attention block's stacked rings — (q, B, K, W,
     hd) K/V rings, or (q, B, W, lanes) latent rings of MLA blocks — with
@@ -412,11 +433,13 @@ def run_stack_decode(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
             if key in rings:
                 h, c = block_decode(cfg, kind, gp[key], gad[key],
                                     {**gc[key], **rings[key]}, h, positions,
-                                    adapter_rows=adapter_rows, layer=layer)
+                                    adapter_rows=adapter_rows, layer=layer,
+                                    ring_row=ring_row)
                 rings[key] = {n: c.pop(n) for n in carried[key]}
             else:
                 h, c = block_decode(cfg, kind, gp[key], gad[key], gc[key], h,
-                                    positions, adapter_rows=adapter_rows)
+                                    positions, adapter_rows=adapter_rows,
+                                    ring_row=ring_row)
             new_c[key] = c
         return (h, layer + 1, rings), new_c
 
@@ -435,6 +458,7 @@ def run_stack_decode(cfg: ModelConfig, groups_p, tail_p, groups_ad, tail_ad,
     new_tail = []
     for i, kind in enumerate(rem):
         x, c = block_decode(cfg, kind, tail_p[i], tail_ad[i], tail_cache[i],
-                            x, positions, adapter_rows=adapter_rows)
+                            x, positions, adapter_rows=adapter_rows,
+                            ring_row=ring_row)
         new_tail.append(c)
     return x, new_groups_cache, tuple(new_tail)

@@ -5,7 +5,10 @@ small size on the CPU with seeded random weights in float32.
 - Served logits: prompt and then decode through the decode step with
   per-slot bank rows and ragged positions, as ``ServeEngine`` runs it,
   match the reference's full forward; the engine's greedy tokens are the
-  reference's argmax.
+  reference's argmax, also for prompts of one and several prefill chunks.
+- A prompt chunk through the decode step (the prefill path) gives the
+  token-by-token logits: its expert layers route each token on its own
+  and drop none, where one dispatch group of the chunk would.
 - The absorbed decode against the latent ring matches ``block_apply``'s
   explicit forward, block by block.
 - The parts every expert share computes, with the shared experts counted
@@ -16,7 +19,7 @@ small size on the CPU with seeded random weights in float32.
 The fine-tuning forward is compared at capacity factor n_experts / top_k,
 where the Switch dispatch keeps every token; dropping under the default
 factor stays open (ROADMAP 2.4).  Decode dispatches one token per row at
-capacity 1, which drops nothing.
+capacity 1, which drops nothing; so does a prefill chunk, token by token.
 """
 import math
 import sys
@@ -141,6 +144,81 @@ def test_engine_tokens_are_the_references_argmax(tiny):
         lg = _ref_logits(c, base, bank, r.rid % 3, toks[:-1])
         p = len(r.prompt)
         np.testing.assert_array_equal(lg[p - 1:].argmax(-1), toks[p:])
+
+
+def test_engine_prefills_long_prompts_to_the_references_argmax(tiny):
+    """Prompts of one token, of one chunk and a token, and of several
+    chunks, three users on two slots: the engine's prefill writes them into
+    the latent rings and its greedy tokens are the reference's argmax."""
+    c, cfg, base, bank = tiny
+    chunk = serve.PREFILL_CHUNK
+    users = {f"u{i}": i for i in range(3)}
+    lens = [(1, 4), (chunk + 1, 3), (2 * chunk + 5, 3)]
+    eng = serve.ServeEngine(cfg, base,
+                            AdapterBank(tree=bank, n_clients=3, rank=4,
+                                        users=users), slots=2,
+                            max_len=2 * chunk + 8)
+    rng = np.random.default_rng(6)
+    reqs = [serve.Request(rid=i, user_id=f"u{i % 3}",
+                          prompt=rng.integers(0, cfg.vocab_size,
+                                              p).astype(np.int32), gen=g)
+            for i, (p, g) in enumerate(lens)]
+    with jax.default_matmul_precision("highest"):
+        done = eng.run(reqs)
+    assert eng.stats.prefill_calls == 0 + 1 + 3
+    for r in reqs:
+        toks = done[r.rid]
+        lg = _ref_logits(c, base, bank, r.rid % 3, toks[:-1])
+        p = len(r.prompt)
+        np.testing.assert_array_equal(lg[p - 1:].argmax(-1), toks[p:])
+
+
+def test_prefill_chunk_drops_no_routed_token(tiny):
+    """At capacity factor 1, 40 tokens in one dispatch group overflow the
+    busiest experts; the decode path's expert layer handed the same 40 as a
+    chunk gives what it gives them one by one.  And the whole decode step,
+    fed a 40-token prompt as chunks of 16 into ring row 2 of three, gives
+    the logits of 40 one-token steps."""
+    c, cfg, base, bank = tiny
+    cfg = cfg.with_overrides(capacity_factor=1.0)
+    p = jax.tree.map(lambda a: a[0], base["groups"]["0"])
+    h = jax.random.normal(jax.random.key(4), (1, 40, cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        grouped, _ = moe.moe_mlp(cfg, p["moe"], h)
+        alone, _ = moe.moe_mlp(cfg, p["moe"], h.reshape(40, 1, -1))
+        chunk, _ = transformer._ffn_decode(cfg, p, {}, h)
+    alone = alone.reshape(h.shape)
+    assert np.abs(np.asarray(grouped - alone)).max() > 1e-3   # it drops
+    np.testing.assert_allclose(chunk, alone, atol=TOL, rtol=TOL)
+
+    toks = np.random.default_rng(3).integers(0, cfg.vocab_size, 40)
+    step = jax.jit(model.decode_step, static_argnums=0)
+    dec = AdapterBank(tree=bank, n_clients=3, rank=4, users={}).decode_tree()
+    with jax.default_matmul_precision("highest"):
+        cache = serve._init_cache(cfg, 3, 48)
+        got = []
+        for start in range(0, 40, 16):
+            part = toks[start:start + 16]
+            n = len(part)
+            lg, cache = step(
+                cfg, base, dec, cache,
+                {"token": np.pad(part, (0, 16 - n))[None].astype(np.int32),
+                 "positions": np.where(np.arange(16) < n,
+                                       start + np.arange(16), -1)[None]},
+                adapter_rows=np.asarray([1], np.int32), ring_row=np.int32(2))
+            got.append(np.asarray(lg[0, :n]))
+        cache = serve._init_cache(cfg, 3, 48)
+        want = []
+        for t in range(40):
+            pos = np.asarray([-1, -1, t], np.int32)
+            lg, cache = step(
+                cfg, base, dec, serve._with_positions(cache, pos),
+                {"token": np.asarray([[0], [0], [toks[t]]], np.int32),
+                 "positions": pos[:, None]},
+                adapter_rows=np.asarray([-1, -1, 1], np.int32))
+            want.append(np.asarray(lg[2, 0]))
+    np.testing.assert_allclose(np.concatenate(got), np.stack(want),
+                               atol=TOL, rtol=TOL)
 
 
 @pytest.mark.parametrize("dense", [True, False])

@@ -7,9 +7,13 @@ into W, decode batch-1).  Covered: batch sizes 1 / 2 / odd / full, more
 requests than slots (continuous-batching slot reuse), duplicate users
 inside one batch, and a Hypothesis property that permuting the request
 stream permutes nothing (outputs are keyed by request, not by slot).
-Also what a profile of the engine reads: ``ServeEngine.stats`` counts
-every slot-step exactly, the ``serve.*`` spans reach the profiler's trace,
-and the compiled step carries its named scopes.
+Prompts go into the ring by the chunked prefill: prompts of one token, of
+exactly one chunk and of several chunks serve the oracle's tokens, and a
+chunk through the decode step gives the logits the token-by-token decode
+gives.  Also what a profile of the engine reads: ``ServeEngine.stats``
+counts every slot-step and prefill call exactly, the ``serve.*`` spans
+reach the profiler's trace, and the compiled step carries its named
+scopes.
 
 Hypothesis is an optional dev dependency (repo convention,
 tests/test_properties.py) — the property test skips on a bare environment.
@@ -22,8 +26,8 @@ import numpy as np
 import pytest
 
 from repro.core import adapter_bank
-from repro.launch.serve import (Request, ServeEngine, make_requests,
-                                serve_naive)
+from repro.launch.serve import (PREFILL_CHUNK, Request, ServeEngine,
+                                make_requests, serve_naive)
 from repro.models import model
 from repro.models.config import get_config
 
@@ -74,6 +78,63 @@ def test_duplicate_users_share_a_batch(setup):
     got = eng.run(reqs)
     ref = serve_naive(cfg, base, bank, reqs)
     _assert_same(reqs, got, ref)
+
+
+@pytest.mark.parametrize("lens", [
+    # a one-token prompt (no prefill call), exactly one chunk, a chunk and
+    # one token, several chunks; 2 slots, so finished slots are reused
+    [(1, 3), (PREFILL_CHUNK, 2), (PREFILL_CHUNK + 1, 2),
+     (2 * PREFILL_CHUNK + 5, 3), (7, 4)],
+])
+def test_prefill_serves_the_per_user_oracle(setup, lens):
+    """Prompts written into the ring by the prefill program, a chunk a call
+    through each user's own bank row, then decoded: the same greedy tokens
+    as the per-user oracle, which feeds every prompt token through the
+    decode step."""
+    cfg, base, bank = setup
+    reqs = _ragged_requests(cfg, bank, lens, seed=4)
+    assert len({r.user_id for r in reqs}) == N_USERS
+    eng = ServeEngine(cfg, base, bank, slots=2,
+                      max_len=max(p + g for p, g in lens))
+    _assert_same(reqs, eng.run(reqs), serve_naive(cfg, base, bank, reqs))
+    assert eng.stats.prefill_tokens == sum(p - 1 for p, _ in lens)
+
+
+def test_a_chunk_gives_the_token_by_token_logits(setup):
+    """The decode step handed 16 tokens a row (S = 16) into ring row 1 of
+    three, three chunks for 37 prompt tokens (the last padded), gives at
+    every position the logits that 37 one-token steps give that row."""
+    from repro.launch import serve
+    cfg, base, bank = setup
+    toks = np.random.default_rng(2).integers(0, cfg.vocab_size, 37)
+    dec = bank.decode_tree()
+    user = np.int32(2)
+    step = jax.jit(model.decode_step, static_argnums=0)
+    with jax.default_matmul_precision("highest"):
+        cache = serve._init_cache(cfg, 3, 40)
+        got = []
+        for start in range(0, 37, 16):
+            part = toks[start:start + 16]
+            n = len(part)
+            lg, cache = step(
+                cfg, base, dec, cache,
+                {"token": np.pad(part, (0, 16 - n))[None].astype(np.int32),
+                 "positions": np.where(np.arange(16) < n,
+                                       start + np.arange(16), -1)[None]},
+                adapter_rows=np.asarray([user]), ring_row=np.int32(1))
+            got.append(np.asarray(lg[0, :n]))
+        cache = serve._init_cache(cfg, 3, 40)
+        want = []
+        for t in range(37):
+            pos = np.asarray([-1, t, -1], np.int32)
+            lg, cache = step(
+                cfg, base, dec, serve._with_positions(cache, pos),
+                {"token": np.asarray([[0], [toks[t]], [0]], np.int32),
+                 "positions": pos[:, None]},
+                adapter_rows=np.asarray([-1, user, -1], np.int32))
+            want.append(np.asarray(lg[1, 0]))
+    np.testing.assert_allclose(np.concatenate(got), np.stack(want),
+                               atol=1e-5, rtol=1e-5)
 
 
 def test_engine_rejects_overlong_request(setup):
@@ -155,24 +216,31 @@ def _ragged_requests(cfg, bank, lens, seed=3):
             for i, (p, g) in enumerate(lens)]
 
 
-@pytest.mark.parametrize("lens,slots", [
-    ([(3, 4), (1, 2), (5, 1), (2, 3)], 4),            # all admitted at once
-    ([(4, 2), (2, 5), (1, 1), (3, 3), (6, 2)], 2),    # slot reuse, a drain
+C = PREFILL_CHUNK
+
+
+@pytest.mark.parametrize("lens,slots,steps", [
+    # all admitted at once: the longest reply sets the steps
+    pytest.param([(3, 4), (1, 2), (C + 2, 1), (2, 3)], 4, 4, id="lens0-4"),
+    # slot reuse, a drain: A(4,2) and B(2,5) at step 0, C(1,1) at 2,
+    # D(2C+5,3) at 3 (steps 3-5), E(6,2) at 5 (steps 5-6)
+    pytest.param([(4, 2), (2, 5), (1, 1), (2 * C + 5, 3), (6, 2)], 2, 7,
+                 id="lens1-2"),
 ])
-def test_stats_count_every_slot_step(setup, lens, slots):
+def test_stats_count_every_slot_step(setup, lens, slots, steps):
     cfg, base, bank = setup
     reqs = _ragged_requests(cfg, bank, lens)
-    eng = ServeEngine(cfg, base, bank, slots=slots, max_len=8)
+    eng = ServeEngine(cfg, base, bank, slots=slots, max_len=2 * C + 8)
     got = eng.run(reqs)
     _assert_same(reqs, got, serve_naive(cfg, base, bank, reqs))
     st = eng.stats
+    assert st.steps == steps
     assert st.slot_steps_emit == sum(g for _, g in lens)
-    assert st.slot_steps_prefill == sum(p - 1 for p, _ in lens)
-    assert (st.slot_steps_prefill + st.slot_steps_emit
-            + st.slot_steps_empty) == slots * st.steps
+    assert st.slot_steps_prefill == 0
+    assert st.slot_steps_emit + st.slot_steps_empty == slots * st.steps
+    assert st.prefill_calls == sum(-(-(p - 1) // C) for p, _ in lens)
+    assert st.prefill_tokens == sum(p - 1 for p, _ in lens)
     assert st.admitted == st.finished == len(lens)
-    # the longest request alone bounds the step count from below
-    assert st.steps >= max(p + g - 1 for p, g in lens)
 
 
 def test_stats_reset_at_each_run(setup):
@@ -184,7 +252,8 @@ def test_stats_reset_at_each_run(setup):
     assert eng.stats is not first
     assert (eng.stats.steps, eng.stats.slot_steps_emit,
             eng.stats.slot_steps_prefill, eng.stats.slot_steps_empty,
-            eng.stats.admitted, eng.stats.finished) == (2, 1, 1, 2, 1, 1)
+            eng.stats.admitted, eng.stats.finished, eng.stats.prefill_calls,
+            eng.stats.prefill_tokens) == (1, 1, 0, 1, 1, 1, 1, 1)
 
 
 def test_decode_step_carries_named_scopes(setup):
@@ -205,6 +274,24 @@ def test_decode_step_carries_named_scopes(setup):
     assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
+def test_prefill_carries_its_scope(setup):
+    """Every operation of the compiled prefill program runs under the
+    ``prefill`` scope that ``serve_prefill_ms`` reads, and the ring's
+    writes under ``kv_ring`` inside it."""
+    from repro.launch import serve
+    cfg, base, bank = setup
+    cache = serve._init_cache(cfg, 2, 8)
+    one = np.int32(0)
+    hlo = serve._prefill.lower(
+        cfg, base, bank.decode_tree(), cache,
+        np.zeros((1, PREFILL_CHUNK), np.int32), one, one, one,
+        one).compile().as_text()
+    names = set(re.findall(r'op_name="(jit[^"]*)"', hlo))
+    assert names and all(n.startswith("jit(_prefill)/prefill/")
+                         for n in names), names
+    assert any("/kv_ring/" in n for n in names)
+
+
 def test_run_writes_its_spans_into_a_profile(setup, tmp_path):
     from jax.profiler import ProfileData
     cfg, base, bank = setup
@@ -222,8 +309,8 @@ def test_run_writes_its_spans_into_a_profile(setup, tmp_path):
                     seen.setdefault(ev.name, []).append(dict(ev.stats))
     st = eng.stats
     assert len(seen["serve.run"]) == len(seen["serve.ring_init"]) == 1
-    for name in ("serve.step", "serve.admit", "serve.dispatch",
-                 "serve.sync", "serve.bookkeep"):
+    for name in ("serve.step", "serve.admit", "serve.prefill",
+                 "serve.dispatch", "serve.sync", "serve.bookkeep"):
         assert len(seen[name]) == st.steps, name
     assert [s["step_num"] for s in seen["serve.step"]] == list(
         range(st.steps))

@@ -9,6 +9,7 @@ layers, which must fit one chip's HBM with the frozen base as an argument.
 At Danube's attention shape: the serving step writes its KV ring in place.
 At DeepSeek-V2-Lite's widths and its cell's slots and ring: the MLA step
 writes its latent ring in place and never builds per-head keys or values.
+At both: the prefill program writes a prompt chunk into the ring in place.
 
 The topology is described inside a module fixture, never at import, and
 the persistent compilation cache is off around these compiles (an entry
@@ -253,3 +254,54 @@ def test_mla_serve_step_updates_the_latent_ring_in_place(one_chip):
     one_layer = math.prod(stacked[1:]) * 2         # bf16
     assert mem.alias_size_in_bytes >= 3 * one_layer
     assert mem.temp_size_in_bytes < one_layer
+
+
+@pytest.mark.parametrize("arch,layers,slots,ring", [
+    ("h2o-danube-3-4b", 2, 8, 384),     # the serving step test's shape
+    ("deepseek-v2-lite", 3, 64, 1408),  # its cell's slots and latent ring
+])
+def test_prefill_writes_the_ring_in_place(one_chip, arch, layers, slots,
+                                          ring):
+    """The prefill program (one slot's chunk of 128 prompt tokens through
+    every layer) at Danube's attention shape and at DeepSeek-V2-Lite's
+    widths (16 of 64 experts held): each layer's chunk goes into the
+    donated stacked ring by in-place scatters, nothing of the ring's or of
+    one layer's shape is materialised (nor their views flattened to rows
+    of lanes, which the chunk's scatter writes through), the rings are
+    aliased input to output, and the scratch is under one layer's ring."""
+    from repro.core.adapter_bank import random_bank
+    from repro.launch import serve
+    from repro.models import model, transformer
+    from repro.models.config import get_config
+    cfg = get_config(arch).with_overrides(
+        n_layers=layers, **({"experts_held": 16} if arch.startswith("deep")
+                            else {}))
+    base = _on(one_chip, jax.eval_shape(
+        lambda: model.init_base(cfg, jax.random.key(0))))
+    bank = _on(one_chip, jax.eval_shape(
+        lambda: random_bank(cfg, 4, jax.random.key(1)).decode_tree()))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: serve._init_cache(cfg, slots, ring)))
+    one = _on(one_chip, jax.ShapeDtypeStruct((), jnp.int32))
+    toks = _on(one_chip, jax.ShapeDtypeStruct((1, serve.PREFILL_CHUNK),
+                                              jnp.int32))
+    compiled = serve._prefill.lower(cfg, base, bank, cache, toks, one, one,
+                                    one, one).compile()
+
+    rings = [a for p, a in jax.tree_util.tree_flatten_with_path(cache)[0]
+             if getattr(p[-1], "key", None) in transformer.RING_NAMES]
+    stacked = max((a.shape for a in rings), key=len)
+    assert stacked[0] == layers - cfg.n_dense_layers and \
+        stacked[-1] % 128 == 0
+    layer = stacked[1:]
+
+    def flat(d):
+        return (math.prod(d[:-1]), d[-1])
+    found, scatters = _ring_traffic(
+        compiled.as_text(), {stacked, layer, (1,) + layer, flat(stacked),
+                             flat(layer)})
+    assert not found, found
+    assert scatters >= 2               # k and v, or the lead layer and scan
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * 2 for a in rings)
+    assert mem.temp_size_in_bytes < math.prod(layer) * 2
